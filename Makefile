@@ -60,4 +60,4 @@ trace-smoke:
 		--trace-out /tmp/repro-trace-smoke.json
 
 serve-example:
-	PYTHONPATH=src $(PY) examples/serve_llm.py --requests 8 --max-new 6
+	PYTHONPATH=src $(PY) examples/serve_llm.py --smoke --requests 8 --max-new 6

@@ -85,6 +85,7 @@ from repro.core import AoTScheduler
 from repro.dispatch import (
     AsyncDispatcher,
     BatchComposer,
+    ChipHeldError,
     ScheduleCache,
     WorkerError,
     WorkerPlane,
@@ -1274,7 +1275,17 @@ def smoke_gate(rows: list[tuple[str, float, str]]) -> list[str]:
 
 def parallel_stepping() -> list[tuple[str, float, str]]:
     """Single-stepper vs per-engine stepping, measured in subprocesses so
-    each mode initializes jax with 2 host devices (one per engine)."""
+    each mode initializes jax with 2 host devices (one per engine).
+
+    Measures CPU host devices only: on an accelerator this process
+    already holds the device, so the child interpreters could not reach
+    it — refused with :class:`~repro.dispatch.ChipHeldError`."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ChipHeldError(
+            f"parallel_stepping measures CPU host devices in child "
+            f"processes; on {backend!r} this process holds the device"
+        )
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"

@@ -21,6 +21,8 @@ def main() -> None:
                     help="also run the (slow) roofline sweep subprocess")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
     from . import (
         dispatch_bench,
         fig2a_overhead_ratio,
@@ -30,6 +32,7 @@ def main() -> None:
         table1_multistream,
     )
 
+    use_compile_cache()
     suites = {
         "fig2a": fig2a_overhead_ratio.run,
         "fig2b": fig2b_sched_minimized.run,

@@ -27,14 +27,19 @@ def model(params, x):
     return out @ params["head"]
 
 
-def main():
-    rng = np.random.default_rng(0)
-    width = 128
+def example_inputs(seed=0, width=128):
+    """Seeded float32 ``(params, x)`` for :func:`model`: x is (32, width)."""
+    rng = np.random.default_rng(seed)
     params = {"stem": rng.standard_normal((width, width), dtype=np.float32) * 0.05,
               "head": rng.standard_normal((width, 16), dtype=np.float32) * 0.05}
     for i in range(8):
         params[f"b{i}"] = rng.standard_normal((width, width), dtype=np.float32) * 0.05
     x = rng.standard_normal((32, width), dtype=np.float32)
+    return params, x
+
+
+def main():
+    params, x = example_inputs()
 
     # --- engines -----------------------------------------------------------
     eager = EagerInterpreter(model, params, x)          # run-time scheduling

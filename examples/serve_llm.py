@@ -1,8 +1,12 @@
 """Multi-tenant serving example: async dispatch over AoT-sealed schedules.
 
-    PYTHONPATH=src python examples/serve_llm.py --requests 24
-    PYTHONPATH=src python examples/serve_llm.py --archs stablelm-1.6b,phi4-mini-3.8b \
-        --fairness weighted --weights 3,1
+    PYTHONPATH=src python examples/serve_llm.py --smoke --requests 24
+    PYTHONPATH=src python examples/serve_llm.py --smoke \
+        --archs stablelm-1.6b,phi4-mini-3.8b --fairness weighted --weights 3,1
+
+``--smoke`` serves each arch's reduced preset in float32 (the CPU size);
+without it the published config is served at its own dtype (bf16), which
+needs an accelerator: ``--max-len`` sizes each engine's KV cache.
 
 Prefill and decode are sealed once per (model, bucket) through the shared
 ``ScheduleCache``; the ``AsyncDispatcher`` steps each tenant on its own
@@ -32,18 +36,19 @@ is ready, in-flight steps always complete) and ``--latency-targets-ms
 deadlines are provably unmeetable are refused with ``AdmissionRejected``
 on their futures instead of poisoning the tail.
 
-    PYTHONPATH=src python examples/serve_llm.py \
+    PYTHONPATH=src python examples/serve_llm.py --smoke \
         --archs stablelm-1.6b,phi4-mini-3.8b \
         --priority-classes 0,1 --latency-targets-ms 5000,0
 
 Multi-process, multi-device: ``--stepping workers`` registers picklable
 ``ServingEngineSpec`` recipes instead of live engines — each worker
 process builds its engines on its own device (round-robin lane
-assignment) and the parent keeps only the O(1) grant path.  On a
-CPU-only host, fake N devices first:
+assignment) and the parent keeps only the O(1) grant path.  CPU hosts
+only: on a TPU the parent holds the chip, so the plane refuses with
+``ChipHeldError`` before spawning.  Fake N devices first:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-    PYTHONPATH=src python examples/serve_llm.py \
+    PYTHONPATH=src python examples/serve_llm.py --smoke \
         --archs stablelm-1.6b,phi4-mini-3.8b \
         --stepping workers --devices 4
 
@@ -58,7 +63,7 @@ futures are awaited alongside the new submissions.  Kill a run
 mid-flight (Ctrl-Z, ``kill -9 %1``) and re-run the same command to
 watch it:
 
-    PYTHONPATH=src python examples/serve_llm.py --requests 24 \
+    PYTHONPATH=src python examples/serve_llm.py --smoke --requests 24 \
         --journal /tmp/serve.db
 
 Observability (``repro.obs``): ``--trace-out trace.json`` records the
@@ -87,6 +92,7 @@ from repro.dispatch import (
     ScheduleCache,
     WorkerPlane,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_model
 from repro.serving import ServingEngine, ServingEngineSpec
 
@@ -95,8 +101,13 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--archs", default="stablelm-1.6b",
                     help="comma-separated model list (each becomes a tenant)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced presets in float32 (CPU size) "
+                         "instead of the published configs at their dtype")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--max-len", type=int, default=128,
+                    help="KV-cache positions per slot (prompt + generated)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--bucketing", default="pow2:8:32",
                     help='"exact", "pow2[:MIN:MAX]", or e.g. "8,16,32"')
@@ -146,6 +157,7 @@ def main():
                          "serving (tenants re-register from journaled "
                          "specs, unfinished requests replay)")
     args = ap.parse_args()
+    use_compile_cache()
 
     tracer = obs.get_tracer()
     if args.trace_out:
@@ -212,7 +224,9 @@ def main():
     t0 = time.perf_counter()
     cfgs = {}
     for arch, weight, cls, target in zip(archs, weights, classes, targets):
-        cfg = dataclasses.replace(C.get(arch, smoke=True), dtype="float32")
+        cfg = C.get(arch, smoke=args.smoke)
+        if args.smoke:
+            cfg = dataclasses.replace(cfg, dtype="float32")
         cfgs[arch] = cfg
         if arch in dispatcher.models:      # rebuilt by recovery above
             continue
@@ -222,15 +236,15 @@ def main():
         # rides along as spec= so a restarted dispatcher can rebuild
         # this lane without us
         recipe = ServingEngineSpec(
-            arch=arch, max_slots=args.slots, max_len=128,
-            bucketing=bucketing, dtype="float32",
+            arch=arch, max_slots=args.slots, max_len=args.max_len,
+            bucketing=bucketing, smoke=args.smoke, dtype=cfg.dtype,
         )
         if workers_mode:
             engine = recipe
         else:
             params, _ = init_model(jax.random.key(0), cfg)
             engine = ServingEngine(
-                cfg, params, max_slots=args.slots, max_len=128,
+                cfg, params, max_slots=args.slots, max_len=args.max_len,
                 bucketing=bucketing, schedule_cache=cache,
             )
         dispatcher.register_model(

@@ -83,6 +83,7 @@ from .bucketing import (
 from .cache import CacheStats, MemoryBudget, ScheduleCache
 from .dispatcher import Dispatcher, DrainTimeoutError, QueueFullError
 from .errors import (
+    ChipHeldError,
     DispatchError,
     FaultInjected,
     IllegalTransition,
@@ -142,7 +143,8 @@ __all__ = [
     "AdmissionRejected", "AdaptiveController", "SLOPolicy",
     "DeviceWorker", "EngineWorker", "WorkerPlane", "device_topology",
     "WorkerError", "WorkerSetupError", "WorkerCrashed", "WorkerTimeout",
-    "DispatchError", "IllegalTransition", "JournalCorrupt", "FaultInjected",
+    "DispatchError", "ChipHeldError", "IllegalTransition", "JournalCorrupt",
+    "FaultInjected",
     "RequestState", "LaneState", "LifecycleTracker",
     "REQUEST_TRANSITIONS", "LANE_TRANSITIONS", "TERMINAL_STATES",
     "check_request_transition", "check_lane_transition",

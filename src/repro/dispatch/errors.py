@@ -18,6 +18,7 @@ The hierarchy::
     ├── IllegalTransition       lifecycle state-machine violation
     ├── JournalCorrupt          unreadable / torn request journal
     ├── FaultInjected           a FaultInjector fired (tests only)
+    ├── ChipHeldError           worker processes refused on a TPU host
     └── WorkerError             worker-plane faults (workers.py)
         ├── WorkerSetupError
         ├── WorkerCrashed
@@ -80,3 +81,11 @@ class FaultInjected(DispatchError):
     """Raised by a :class:`~repro.dispatch.journal.FaultInjector` hook —
     the deterministic stand-in for a crash in recovery tests.  Never
     raised in production paths unless an injector is installed."""
+
+
+class ChipHeldError(DispatchError):
+    """Raised before any worker process is spawned when the backend is a
+    TPU: one process holds the chip, and a parent that has touched JAX
+    (which the plane's device topology does) holds it, so spawned
+    workers needing it would fail or hang in setup.  Serve every TPU
+    device from one process instead (engines pinned per device)."""

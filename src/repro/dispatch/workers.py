@@ -69,7 +69,7 @@ from typing import Any, Callable, Optional
 
 from repro.obs.tracer import TraceEvent, get_tracer
 
-from .errors import DispatchError
+from .errors import ChipHeldError, DispatchError
 
 
 class WorkerError(DispatchError):
@@ -392,15 +392,22 @@ def device_topology(n_workers: int) -> list[int]:
     Consults :func:`repro.launch.mesh.host_device_count` (the same
     ``jax.devices()`` view ``make_host_mesh`` and the sharding rules are
     built over); worker ``i`` serves device ``i % device_count``, so a
-    plane wider than the host wraps rather than failing.  Falls back to
-    a single device when the accelerator runtime is unavailable."""
-    try:
-        from repro.launch.mesh import host_device_count
+    plane wider than the host wraps rather than failing.  Raises
+    :class:`~repro.dispatch.errors.ChipHeldError` on a TPU backend: this
+    very call has made the parent hold the chip."""
+    import jax
 
-        n_dev = host_device_count()
-    except Exception:  # noqa: BLE001 - no runtime: single-device fallback
-        n_dev = 1
-    n_dev = max(1, int(n_dev))
+    from repro.launch.mesh import host_device_count
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        raise ChipHeldError(
+            "worker processes cannot serve a TPU: the parent process holds "
+            "the chip once it touches JAX, so spawned workers would fail or "
+            "hang in setup; serve from one process (engines pinned per "
+            "device, stepping='pool' or 'per-engine')"
+        )
+    n_dev = host_device_count()
     return [i % n_dev for i in range(max(0, n_workers))]
 
 

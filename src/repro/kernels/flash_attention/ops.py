@@ -1,8 +1,10 @@
 """jit'd wrapper: model-layout flash attention.
 
 Takes model-layout tensors (B, S, heads, head_dim), flattens to the kernel's
-(B·heads, S, head_dim) layout, and picks kernel vs oracle by backend —
-Pallas-on-TPU, interpret-Pallas or the oracle on CPU.
+(B·heads, S, head_dim) layout, and runs the Pallas kernel (compiled on
+TPU, interpreted with ``interpret=True``) or, with ``use_kernel=False``,
+the oracle.  Asking for the compiled kernel off TPU raises: it never
+quietly becomes the oracle.
 """
 
 from __future__ import annotations
@@ -16,8 +18,13 @@ from .kernel import flash_attention
 from .ref import flash_attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _require_kernel_backend(interpret: bool) -> None:
+    backend = jax.default_backend()
+    if not interpret and backend != "tpu":
+        raise ValueError(
+            f"the compiled flash-attention kernel needs a TPU, not "
+            f"{backend!r}: pass interpret=True or use_kernel=False"
+        )
 
 
 @functools.partial(
@@ -42,9 +49,10 @@ def mha_flash(
     qf = q.transpose(0, 2, 1, 3).reshape(B * NH, Sq, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * NKV, k.shape[1], hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * NKV, v.shape[1], hd)
-    fn = flash_attention if (use_kernel and (interpret or _on_tpu())) else flash_attention_ref
     kw = dict(group=group, scale=scale, softcap=softcap, causal=causal, window=window)
-    if fn is flash_attention:
-        kw["interpret"] = interpret or not _on_tpu()
-    out = fn(qf, kf, vf, **kw)
+    if use_kernel:
+        _require_kernel_backend(interpret)
+        out = flash_attention(qf, kf, vf, interpret=interpret, **kw)
+    else:
+        out = flash_attention_ref(qf, kf, vf, **kw)
     return out.reshape(B, NH, Sq, hd).transpose(0, 2, 1, 3)

@@ -1,9 +1,10 @@
 """jit'd public wrapper for stream_pack.
 
 ``packed_branches(xs, ws)`` is the drop-in for "run these k independent
-matmuls on k streams": stack, one kernel, unstack.  On CPU (tests, smoke) it
-dispatches the Pallas kernel in interpret mode or falls back to the jnp
-oracle; on TPU the Pallas path is the real kernel.
+matmuls on k streams": stack, one kernel, unstack.  On TPU the Pallas path
+is the real kernel; elsewhere pass ``interpret=True`` (tests, smoke) or
+``use_kernel=False`` for the jnp oracle.  Asking for the compiled kernel
+off TPU raises: it never quietly becomes the oracle.
 """
 
 from __future__ import annotations
@@ -17,15 +18,21 @@ from .kernel import stream_pack_matmul
 from .ref import stream_pack_matmul_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _require_kernel_backend(interpret: bool) -> None:
+    backend = jax.default_backend()
+    if not interpret and backend != "tpu":
+        raise ValueError(
+            f"the compiled stream_pack kernel needs a TPU, not {backend!r}: "
+            f"pass interpret=True or use_kernel=False"
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def stream_pack(x, w, *, use_kernel: bool = True, interpret: bool = False):
     """x: (lanes, M, K), w: (lanes, K, N) → (lanes, M, N)."""
-    if use_kernel and (interpret or _on_tpu()):
-        return stream_pack_matmul(x, w, interpret=interpret or not _on_tpu())
+    if use_kernel:
+        _require_kernel_backend(interpret)
+        return stream_pack_matmul(x, w, interpret=interpret)
     return stream_pack_matmul_ref(x, w)
 
 

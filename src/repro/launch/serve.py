@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 import repro.configs as C
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_model
 from repro.serving import Request, ServingEngine
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--dtype", default="float32")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = C.get(args.arch, smoke=args.smoke)
     cfg = dataclasses.replace(cfg, dtype=args.dtype)

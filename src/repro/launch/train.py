@@ -23,6 +23,7 @@ import repro.configs as C
 from repro.data import Prefetcher, SyntheticLM, data_config_for
 from repro.checkpoint import save_checkpoint
 from repro.distributed.sharding import tree_shardings, use_sharding_ctx
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_model
 from repro.models.transformer import abstract_model
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--dtype", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = C.get(args.arch, smoke=args.smoke)
     if args.dtype:

@@ -17,6 +17,7 @@ that use the same cache, and evicted LRU under shape churn.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -92,6 +93,47 @@ class EngineStats:
     def decode_tok_per_s(self) -> float:
         """Decode-only token throughput (tokens out / decode seconds)."""
         return self.tokens_out / self.decode_s if self.decode_s else 0.0
+
+
+def decode_body(params, cache, tokens, *, cfg):
+    """Sealed decode step: one greedy token for every slot.
+
+    Module-level (``cfg`` bound with ``functools.partial``) so the body
+    the engine seals can also be lowered from abstract shapes alone."""
+    logits, cache = decode_step(params, cache, tokens, cfg)
+    nxt = jnp.argmax(logits[:, :, : cfg.vocab], axis=-1).astype(jnp.int32)
+    return nxt, cache
+
+
+def prefill_body(params, tokens, cache, slot, true_len, *, cfg):
+    """Sealed prefill: one request (padded to a bucket) into cache slot
+    ``slot``; returns its first token and the updated cache."""
+    max_slots = cache["pos"].shape[0]
+    # run the padded prompt through decode-style attention with cache,
+    # writing K/V at offsets [0, P) of the slot.
+    sub_cache = jax.tree_util.tree_map(
+        lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
+        if c.ndim >= 2 and c.shape[1] == max_slots
+        else c,
+        {k: v for k, v in cache.items() if k != "pos"},
+    )
+    sub_cache["pos"] = jnp.zeros((1,), jnp.int32)
+    logits, sub_cache = decode_step(params, sub_cache, tokens, cfg)
+    # next token from the true last prompt position (pre-pad)
+    last = logits[0, true_len - 1, : cfg.vocab]
+    nxt = jnp.argmax(last).astype(jnp.int32)
+    # write slot state back
+    new_cache = {}
+    for k, v in cache.items():
+        if k == "pos":
+            new_cache[k] = v.at[slot].set(true_len)
+        elif v.ndim >= 2 and v.shape[1] == max_slots:
+            new_cache[k] = jax.lax.dynamic_update_slice_in_dim(
+                v, sub_cache[k].astype(v.dtype), slot, axis=1
+            )
+        else:
+            new_cache[k] = v
+    return nxt, new_cache
 
 
 class ServingEngine:
@@ -277,7 +319,7 @@ class ServingEngine:
         )
 
         def build():
-            exe = jax.jit(self._decode_impl).lower(
+            exe = jax.jit(functools.partial(decode_body, cfg=self.cfg)).lower(
                 self.params, self.kv_cache,
                 jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32),
             ).compile()
@@ -316,7 +358,7 @@ class ServingEngine:
         key = self._prefill_key(bucket)
 
         def build():
-            exe = jax.jit(self._prefill_dyn).lower(
+            exe = jax.jit(functools.partial(prefill_body, cfg=self.cfg)).lower(
                 self.params,
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                 self.kv_cache,
@@ -330,42 +372,6 @@ class ServingEngine:
             key, build,
             arena_bytes=self._exec_arena_bytes(((1, bucket), jnp.int32)),
         )
-
-    # -- sealed step bodies ------------------------------------------------
-    def _decode_impl(self, params, cache, tokens):
-        logits, cache = decode_step(params, cache, tokens, self.cfg)
-        nxt = jnp.argmax(logits[:, :, : self.cfg.vocab], axis=-1).astype(jnp.int32)
-        return nxt, cache
-
-    def _prefill_dyn(self, params, tokens, cache, slot, true_len):
-        """Prefill one request (padded to a bucket) into cache slot `slot`."""
-        cfg = self.cfg
-        B1, P = tokens.shape
-        # run the padded prompt through decode-style attention with cache,
-        # writing K/V at offsets [0, P) of the slot.
-        sub_cache = jax.tree_util.tree_map(
-            lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
-            if c.ndim >= 2 and c.shape[1] == self.max_slots
-            else c,
-            {k: v for k, v in cache.items() if k != "pos"},
-        )
-        sub_cache["pos"] = jnp.zeros((1,), jnp.int32)
-        logits, sub_cache = decode_step(params, sub_cache, tokens, cfg)
-        # next token from the true last prompt position (pre-pad)
-        last = logits[0, true_len - 1, : cfg.vocab]
-        nxt = jnp.argmax(last).astype(jnp.int32)
-        # write slot state back
-        new_cache = {}
-        for k, v in cache.items():
-            if k == "pos":
-                new_cache[k] = v.at[slot].set(true_len)
-            elif v.ndim >= 2 and v.shape[1] == self.max_slots:
-                new_cache[k] = jax.lax.dynamic_update_slice_in_dim(
-                    v, sub_cache[k].astype(v.dtype), slot, axis=1
-                )
-            else:
-                new_cache[k] = v
-        return nxt, new_cache
 
     def compose_key(self) -> tuple:
         """Batched-decode compatibility key for the batch composer.

@@ -67,7 +67,8 @@ class ServingEngineSpec(EngineSpec):
 
     ``build`` resolves the config (``smoke=True`` keeps worker start-up
     CI-sized), initializes parameters from ``seed``, places the engine on
-    the worker's device, and seals schedules through the worker's shared
+    the worker's device (a ``device_index`` the process cannot see
+    raises ``ValueError``), and seals schedules through the worker's shared
     cache — so registration cost is paid in the worker, and parent
     steppers still never compile."""
 
@@ -88,12 +89,17 @@ class ServingEngineSpec(EngineSpec):
 
         from .engine import ServingEngine
 
+        devices = jax.devices()
+        if not 0 <= device_index < len(devices):
+            raise ValueError(
+                f"device_index {device_index} is not one of the "
+                f"{len(devices)} visible device(s)"
+            )
+        device = devices[device_index]
         cfg = C.get(self.arch, smoke=self.smoke)
         if self.dtype is not None:
             cfg = dataclasses.replace(cfg, dtype=self.dtype)
         params, _ = init_model(jax.random.key(self.seed), cfg)
-        devices = jax.devices()
-        device = devices[device_index % len(devices)]
         return ServingEngine(
             cfg, params, max_slots=self.max_slots, max_len=self.max_len,
             bucketing=self.bucketing, schedule_cache=schedule_cache,

@@ -169,3 +169,16 @@ def test_mha_flash_model_layout_matches_model_attention():
         q_pos=jnp.arange(S), kv_pos=jnp.arange(S), window=16, kv_valid=None,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("call", [
+    lambda: mha_flash(*(_rand((1, 64, 2, 32), jnp.float32) for _ in range(3))),
+    lambda: stream_pack(_rand((2, 64, 64), jnp.float32),
+                        _rand((2, 64, 64), jnp.float32)),
+])
+def test_compiled_kernel_off_tpu_raises_not_falls_back(call):
+    """use_kernel=True without interpret=True on a non-TPU backend must
+    raise, never quietly run the jnp oracle in the kernel's place."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(ValueError, match="needs a TPU"):
+        call()

@@ -39,13 +39,14 @@ from _scenarios import SetupFailWorker, WorkerTickSpec
 from repro import obs
 from repro.dispatch import (
     AsyncDispatcher,
+    ChipHeldError,
     WorkerCrashed,
     WorkerError,
     WorkerPlane,
     WorkerSetupError,
     WorkerTimeout,
 )
-from repro.serving import Request
+from repro.serving import Request, ServingEngineSpec
 
 START_METHODS = ("fork", "spawn")
 
@@ -331,3 +332,43 @@ def test_trace_merge_has_per_process_tracks():
     for pid in worker_pids:
         assert tracks[pid] == f"worker pid={pid}"
     _assert_no_orphans()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Make JAX report a TPU backend (the process keeps its CPU devices)."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_worker_plane_refuses_tpu_before_spawning(tpu_backend):
+    """On a TPU the parent holds the chip once it touches JAX: the plane,
+    the workers front door, and the CPU parallel-stepping bench refuse
+    typed instead of spawning children that would hang in setup."""
+    with pytest.raises(ChipHeldError, match="holds the chip"):
+        WorkerPlane(2, start_method="spawn")
+    with pytest.raises(ChipHeldError):
+        AsyncDispatcher(stepping="workers", devices=1)
+    assert mp.active_children() == []
+
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks.dispatch_bench import parallel_stepping
+
+    with pytest.raises(ChipHeldError):
+        parallel_stepping()
+    assert mp.active_children() == []
+
+
+def test_engine_spec_refuses_an_invisible_device():
+    """A device index beyond the visible devices raises; it never wraps
+    onto device 0."""
+    import jax
+
+    n = len(jax.devices())
+    for bad in (n, -1):
+        with pytest.raises(ValueError, match="visible device"):
+            ServingEngineSpec().build(bad)
