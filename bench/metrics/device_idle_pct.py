@@ -1,0 +1,6 @@
+"""Device: share of the traced window in which no operation ran on the
+device (averaged over the chips used), in percent."""
+
+
+def read(ctx):
+    return 100.0 * (1.0 - ctx.trace.busy_s() / (ctx.trace.window_ns / 1e9))
