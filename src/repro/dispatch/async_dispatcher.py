@@ -226,16 +226,12 @@ class _QuantumArbiter:
             self._waiting[lane] = slot
             self._pump_locked()
             timed = False
-            parked = False
             while slot.lane is None:
                 if self._closed or slot.evicted:
                     if self._waiting.get(lane) is slot:
                         del self._waiting[lane]
                         self._promote_ticker_locked()
                     return False
-                if not parked and self._tracer.enabled:
-                    parked = True
-                    self._tracer.instant("park", cat="arbiter", lane=lane)
                 slot.timed_wait = self._ticker_locked() is slot
                 expired = not slot.cv.wait(
                     self._tick if slot.timed_wait else None
@@ -244,13 +240,9 @@ class _QuantumArbiter:
                 timed = expired        # attribute the grant to ITS wakeup
                 if expired:
                     self.timed_wakeups += 1
-                    if self._tracer.enabled:
-                        self._tracer.instant("tick", cat="arbiter", lane=lane)
                     self._pump_locked()
             if timed:
                 self.timed_grants += 1
-            if parked and self._tracer.enabled:
-                self._tracer.instant("wake", cat="arbiter", lane=lane)
             return not self._closed
 
     def acquire_any(self) -> Optional[str]:
@@ -269,10 +261,6 @@ class _QuantumArbiter:
                         lane, slot.lane = slot.lane, None
                         if timed:
                             self.timed_grants += 1
-                        if self._tracer.enabled:
-                            self._tracer.instant(
-                                "wake", cat="arbiter", lane=lane
-                            )
                         return lane
                     lane = self._pick_locked(slot.since)
                     if lane is not None:
@@ -285,8 +273,6 @@ class _QuantumArbiter:
                     # cascades into waking the next worker, and the next)
                     if id(slot) not in self._parked:
                         self._parked[id(slot)] = slot
-                        if self._tracer.enabled:
-                            self._tracer.instant("park", cat="arbiter")
                     slot.timed_wait = self._ticker_locked() is slot
                     expired = not slot.cv.wait(
                         self._tick if slot.timed_wait else None
@@ -295,8 +281,6 @@ class _QuantumArbiter:
                     timed = expired    # attribute the grant to ITS wakeup
                     if expired:
                         self.timed_wakeups += 1
-                        if self._tracer.enabled:
-                            self._tracer.instant("tick", cat="arbiter")
                         # the designated ticker is the one executor awake on
                         # a wall-clock cadence, so it owns the idle-period
                         # occupancy samples — without this, the series only

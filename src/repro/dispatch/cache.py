@@ -339,9 +339,6 @@ class ScheduleCache:
             self._entries.move_to_end(key)
             entry.touched = time.monotonic()
             self.stats.hits += 1
-            if self.tracer.enabled:
-                # no repr(key): hits are the hot path
-                self.tracer.instant("cache.hit", cat="cache")
             return entry.value
 
     def put(
@@ -386,8 +383,6 @@ class ScheduleCache:
                 self._entries.move_to_end(key)
                 entry.touched = time.monotonic()
                 self.stats.hits += 1
-                if self.tracer.enabled:
-                    self.tracer.instant("cache.hit", cat="cache")
                 return entry.value
             self.stats.misses += 1
             lock = self._build_locks.setdefault(key, threading.Lock())
@@ -402,8 +397,6 @@ class ScheduleCache:
                     entry.touched = time.monotonic()
                     self.stats.hits += 1
                     self.stats.misses -= 1
-                    if self.tracer.enabled:
-                        self.tracer.instant("cache.hit", cat="cache")
                     return entry.value
             t0 = time.perf_counter()
             # on failure the per-key lock stays in _build_locks: waiters and

@@ -88,11 +88,14 @@ class _Lane:
     lane drains out; ``retire_future`` resolves to the engine once the
     drained lane's removal finalizes, and ``finalizing`` (also under
     ``queue_mu``) makes that finalization once-only no matter how many
-    steppers observe the drain.  Internal to the dispatcher."""
+    steppers observe the drain.  ``step_span`` is the name of the lane's
+    ``step:<lane>`` trace span, built once here rather than per step.
+    Internal to the dispatcher."""
 
     __slots__ = (
         "name", "engine", "queue", "queue_mu", "step_mu", "retired",
         "priority_class", "finalizing", "retire_future", "lc_state",
+        "step_span",
     )
 
     def __init__(
@@ -108,6 +111,7 @@ class _Lane:
         self.finalizing = False
         self.retire_future: Optional[Future] = None
         self.lc_state = ""   # stamped by LifecycleTracker.lane_begin
+        self.step_span = f"step:{name}"
 
 
 class Dispatcher:
@@ -923,22 +927,20 @@ class Dispatcher:
                     engine.submit(req)
             stats = getattr(engine, "stats", None)
             tok_before = self._engine_tokens(stats)
-            t0 = time.perf_counter()
-            newly = engine.step()
-            dt = time.perf_counter() - t0
-            if tok_before is not None:
-                tokens = self._engine_tokens(stats) - tok_before
-            else:
-                # duck-typed engine without token stats: charge a finished
-                # request's output in one burst at completion
-                tokens = sum(len(r.generated) for r in newly)
-            if self.tracer.enabled:
-                # span lands on the stepping thread's track — in pool mode
-                # that is what makes multi-worker overlap visible
-                self.tracer.complete(
-                    f"step:{name}", t0, dt, cat="step", lane=name,
-                    args={"tokens": tokens, "finished": len(newly)},
-                )
+            # span lands on the stepping thread's track — in pool mode
+            # that is what makes multi-worker overlap visible
+            with self.tracer.span(lane.step_span, cat="step", lane=name) as span:
+                t0 = time.perf_counter()
+                newly = engine.step()
+                dt = time.perf_counter() - t0
+                if tok_before is not None:
+                    tokens = self._engine_tokens(stats) - tok_before
+                else:
+                    # duck-typed engine without token stats: charge a
+                    # finished request's output in one burst at completion
+                    tokens = sum(len(r.generated) for r in newly)
+                if span:
+                    span.args = {"tokens": tokens, "finished": len(newly)}
         # lifecycle transitions for this quantum's admissions, after the
         # step lock is released: the quantum popped them (GRANTED) and
         # handed them to the engine (STEPPING).  A crash before these

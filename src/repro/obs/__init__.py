@@ -1,12 +1,17 @@
 """Unified observability plane: spans, trace export, metrics registry.
 
-Three pieces, one import surface:
+Four pieces, one import surface:
 
 * :mod:`repro.obs.tracer` — :class:`SpanTracer`, a lock-light per-thread
   ring-buffer recorder for the request lifecycle (submit → queued →
   granted → step → complete/failed), arbiter, cache, and pool events.
   :func:`get_tracer` returns the process-wide default instance every
-  dispatch component falls back to.
+  dispatch component falls back to.  :meth:`SpanTracer.span` opens a
+  scoped span that also shows in a ``jax.profiler`` trace, on the
+  profiler's clock.
+* :mod:`repro.obs.stall` — :class:`StallWatch`, the heartbeat an enabled
+  tracer runs: it records ``host.stall`` spans, with what the process did
+  meanwhile, when the whole process stops.
 * :mod:`repro.obs.export` — Chrome trace-event / Perfetto JSON export
   (:func:`to_chrome_trace` / :func:`write_chrome_trace`), structural
   validation (:func:`validate_trace`), and analysis helpers
@@ -49,7 +54,8 @@ from .registry import (
     register_worker_plane,
     samples_from_dict,
 )
-from .tracer import SpanTracer, TraceEvent, get_tracer
+from .stall import StallWatch
+from .tracer import Span, SpanTracer, TraceEvent, get_tracer
 
 __all__ = [
     "Counter",
@@ -57,7 +63,9 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "Sample",
+    "Span",
     "SpanTracer",
+    "StallWatch",
     "TraceEvent",
     "composed_spans",
     "get_tracer",

@@ -5,11 +5,22 @@ the scheduling gap (Fig. 2) before it could remove it, and every dispatch
 claim this repo makes (multi-worker overlap, sub-tick grant latency, flat
 per-grant CPU) is currently proven only by counters buried in tests.  The
 tracer records the full request lifecycle — ``submit → queued → granted →
-step[i] → complete`` — plus arbiter events (grant, park, wake, timed
-tick), schedule-cache events (build spans, hits, byte-evictions), and
-stepper-pool occupancy transitions, correlated by request id + lane +
+step[i] → complete`` — plus arbiter grants, schedule-cache events
+(build spans, byte-evictions), the engine's host work inside a step
+(launches, input puts, token read-backs, slot resets), and stepper-pool
+occupancy transitions, correlated by request id + lane +
 recording thread, so :mod:`repro.obs.export` can render the overlap
 ``chrome://tracing`` / Perfetto actually shows.
+
+Scoped spans (:meth:`SpanTracer.span`) go to two places at once: the
+thread's ring, on the tracer's clock, and — while the span is open — a
+profiler ``TraceMe`` of the same name (``jax.profiler.TraceAnnotation``),
+so a profile taken with ``jax.profiler`` shows the program's own spans on
+the profiler's clock, beside the device's work.  The rings cannot be
+mapped onto a profile afterwards: the profiler stamps its events relative
+to its own session.  An enabled tracer also runs a
+:class:`repro.obs.stall.StallWatch`, which records ``host.stall`` spans
+when the whole process stops.
 
 Design constraints (DESIGN.md §observability):
 
@@ -17,7 +28,9 @@ Design constraints (DESIGN.md §observability):
   branch — ``if tracer.enabled: tracer.instant(...)`` — so a disabled
   tracer costs a single attribute load + comparison and never builds the
   event's arguments.  The emit methods *also* re-check ``enabled``, so an
-  unguarded call site is still safe, just marginally slower.
+  unguarded call site is still safe, just marginally slower.  A disabled
+  :meth:`SpanTracer.span` returns one shared no-op context manager, and no
+  thread runs.
 * **Thread-owned ring buffers.**  Each recording thread appends to its
   own bounded ring (``collections.deque(maxlen=...)``) reached through
   ``threading.local`` — the only shared lock is taken once per thread,
@@ -40,10 +53,13 @@ async begin/end (one async track per request id), ``C`` counters.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, NamedTuple, Optional
+
+from .stall import StallWatch
 
 
 class TraceEvent(NamedTuple):
@@ -87,6 +103,84 @@ class _Ring:
         self.emitted = 0
 
 
+class _NullSpan:
+    """The span a disabled tracer hands out: does nothing, holds nothing,
+    and is falsy, so ``if span: span.args = {...}`` builds no arguments."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
+    def __bool__(self) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+_ANNOTATION: Any = None          # jax.profiler.TraceAnnotation, once found
+
+
+def _annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` if the program has imported JAX's
+    profiler, else None.  Never imports JAX itself: a process without it
+    has no profiler session to annotate, and its spans go to the ring
+    only."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        mod = sys.modules.get("jax.profiler")
+        _ANNOTATION = getattr(mod, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+class Span:
+    """An open scoped span of an enabled tracer (see :meth:`SpanTracer.span`).
+
+    ``args`` may be (re)assigned inside the ``with`` block — e.g. to
+    counts known only once the work is done; they reach the ring, while
+    the profiler's ``TraceMe`` carries the arguments given at the start."""
+
+    __slots__ = ("_tracer", "name", "cat", "lane", "rid", "args", "_ann", "_t0")
+
+    def __init__(self, tracer: "SpanTracer", name: str, cat: str, lane: str,
+                 rid: Optional[int], args: Optional[dict]) -> None:
+        self._tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.lane = lane
+        self.rid = rid
+        self.args = args
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Span":
+        cls = _annotation()
+        if cls is not None:
+            meta = {}
+            if self.rid is not None:
+                meta["rid"] = self.rid
+            if self.lane:
+                meta["lane"] = self.lane
+            if self.args:
+                meta.update((k, v) for k, v in self.args.items()
+                            if isinstance(v, (int, float, str)))
+            self._ann = cls(self.name, **meta)
+            self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        t1 = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer.complete(self.name, self._t0, t1 - self._t0, cat=self.cat,
+                              lane=self.lane, rid=self.rid, args=self.args)
+        return False
+
+
 class SpanTracer:
     """Per-thread ring-buffer recorder for dispatch-plane trace events.
 
@@ -95,7 +189,8 @@ class SpanTracer:
     and starts **disabled**: instrumented code runs at production speed
     until :meth:`enable` is called.  All methods are safe from any
     thread; emits never take a shared lock (see the module docstring for
-    the ownership contract).
+    the ownership contract).  While enabled it runs a
+    :class:`~repro.obs.stall.StallWatch` (:attr:`watch`).
     """
 
     def __init__(
@@ -112,17 +207,25 @@ class SpanTracer:
         self._local = threading.local()
         self._mu = threading.Lock()          # ring registry only
         self._rings: list[_Ring] = []
+        self.watch: Optional[StallWatch] = None
 
     # -- lifecycle ---------------------------------------------------------
 
     def enable(self) -> "SpanTracer":
-        """Start recording (idempotent); returns ``self`` for chaining."""
+        """Start recording and the host-stall watch (idempotent; a forked
+        child that calls it gets a watch of its own); returns ``self``."""
         self.enabled = True
+        if self.watch is None or not self.watch.running:
+            self.watch = StallWatch(self).start()
         return self
 
     def disable(self) -> "SpanTracer":
-        """Stop recording (idempotent); buffered events stay drainable."""
+        """Stop recording and the watch (idempotent); buffered events stay
+        drainable."""
         self.enabled = False
+        watch, self.watch = self.watch, None
+        if watch is not None:
+            watch.stop()
         return self
 
     def clear(self) -> None:
@@ -182,6 +285,26 @@ class SpanTracer:
         ring = self._ring()
         ring.emitted += 1
         ring.buf.append((ts, "X", cat, name, max(0.0, dur), rid, lane, args))
+
+    def span(
+        self,
+        name: str,
+        *,
+        cat: str,
+        lane: str = "",
+        rid: Optional[int] = None,
+        args: Optional[dict] = None,
+    ) -> Any:
+        """A scoped span: ``with tracer.span("decode", cat="engine"): ...``.
+
+        Disabled, returns the shared no-op :data:`NULL_SPAN` (falsy).
+        Enabled, returns a :class:`Span` that, while open, holds a profiler
+        ``TraceMe`` of the same name (``rid``, ``lane`` and the scalar
+        ``args`` as its metadata), and on exit records the same ``X``
+        event as :meth:`complete`, on this tracer's clock."""
+        if not self.enabled:
+            return NULL_SPAN
+        return Span(self, name, cat, lane, rid, args)
 
     def async_begin(
         self,

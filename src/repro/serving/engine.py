@@ -40,6 +40,8 @@ class Request:
     """One generation request: prompt in, tokens out, engine-stamped
     timestamps (``t_submit``/``t_first``/``t_done``) for latency metrics.
     The unit of traffic for both the engine and the dispatch layer.
+    ``t_first`` is stamped once the first token has been read back from
+    the device to the host, so it includes the request's prefill.
 
     ``truncated`` is set when the engine stopped the request early because
     its context window filled (``prompt + generated`` reached ``max_len``)
@@ -93,6 +95,19 @@ class EngineStats:
     def decode_tok_per_s(self) -> float:
         """Decode-only token throughput (tokens out / decode seconds)."""
         return self.tokens_out / self.decode_s if self.decode_s else 0.0
+
+
+_PREFILL_READBACK = {"kind": "prefill"}
+_DECODE_READBACK = {"kind": "decode"}
+
+
+def _bind_cfg(body: Callable, cfg) -> Callable:
+    """``body`` with ``cfg`` bound, keeping ``body``'s name: ``jax.jit``
+    names the sealed program after it, so the device trace shows
+    ``jit_decode_body`` rather than ``jit__unknown``."""
+    bound = functools.partial(body, cfg=cfg)
+    bound.__name__ = bound.__qualname__ = body.__name__
+    return bound
 
 
 def decode_body(params, cache, tokens, *, cfg):
@@ -319,7 +334,7 @@ class ServingEngine:
         )
 
         def build():
-            exe = jax.jit(functools.partial(decode_body, cfg=self.cfg)).lower(
+            exe = jax.jit(_bind_cfg(decode_body, self.cfg)).lower(
                 self.params, self.kv_cache,
                 jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32),
             ).compile()
@@ -358,7 +373,7 @@ class ServingEngine:
         key = self._prefill_key(bucket)
 
         def build():
-            exe = jax.jit(functools.partial(prefill_body, cfg=self.cfg)).lower(
+            exe = jax.jit(_bind_cfg(prefill_body, self.cfg)).lower(
                 self.params,
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                 self.kv_cache,
@@ -432,11 +447,12 @@ class ServingEngine:
         return b
 
     def _finish(self, req: Request, slot: int) -> None:
-        req.done = True
-        req.t_done = time.perf_counter()
-        self.slots[slot] = None
-        # reset the slot's write offset for the next occupant
-        self.kv_cache["pos"] = self.kv_cache["pos"].at[slot].set(0)
+        with self.tracer.span("engine.finish", cat="engine", rid=req.rid):
+            req.done = True
+            req.t_done = time.perf_counter()
+            self.slots[slot] = None
+            # reset the slot's write offset for the next occupant
+            self.kv_cache["pos"] = self.kv_cache["pos"].at[slot].set(0)
 
     def _admit(self) -> list[Request]:
         finished: list[Request] = []
@@ -460,28 +476,29 @@ class ServingEngine:
             exe = self._get_prefill_exec(b)    # schedule-cache hit when warm
             padded = np.zeros((1, b), np.int32)
             padded[0, :plen] = req.prompt
+            tr = self.tracer
             t0 = time.perf_counter()
-            nxt, self.kv_cache = exe(
-                self.params, jnp.asarray(padded), self.kv_cache,
-                jnp.int32(slot), jnp.int32(plen),
-            )
-            dt = time.perf_counter() - t0
-            self.stats.prefill_s += dt
-            if self.tracer.enabled:
-                # nests inside the dispatcher's step span (same thread)
-                self.tracer.complete(
-                    "prefill", t0, dt, cat="engine", rid=req.rid,
-                    args={"bucket": b},
-                )
+            # the spans nest inside the dispatcher's step span (same
+            # thread); prefill times the launch, the device's time is in
+            # the device trace
+            with tr.span("prefill", cat="engine", rid=req.rid,
+                         args={"bucket": b} if tr.enabled else None):
+                with tr.span("engine.h2d", cat="engine"):
+                    tokens = jnp.asarray(padded)
+                    at, length = jnp.int32(slot), jnp.int32(plen)
+                nxt, self.kv_cache = exe(self.params, tokens, self.kv_cache, at, length)
+            self.stats.prefill_s += time.perf_counter() - t0
+            with tr.span("engine.readback", cat="engine", args=_PREFILL_READBACK):
+                first = int(nxt)
             req.t_first = time.perf_counter()
-            req.generated.append(int(nxt))
+            req.generated.append(first)
             self.stats.prefill_tokens += 1
             if len(req.generated) >= req.max_new_tokens:
                 # e.g. a 1-token request: done at prefill, never seats
                 self._finish(req, slot)
                 finished.append(req)
                 continue
-            self._next_tok[slot, 0] = int(nxt)
+            self._next_tok[slot, 0] = first
             self.slots[slot] = req
         return finished
 
@@ -509,18 +526,17 @@ class ServingEngine:
         live = [s for s in range(self.max_slots) if self.slots[s] is not None]
         if not live:
             return finished
+        tr = self.tracer
         t0 = time.perf_counter()
-        nxt, self.kv_cache = self._decode(
-            self.params, self.kv_cache, jnp.asarray(self._next_tok)
-        )
-        dt = time.perf_counter() - t0
-        self.stats.decode_s += dt
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "decode", t0, dt, cat="engine", args={"live": len(live)}
-            )
+        with tr.span("decode", cat="engine",
+                     args={"live": len(live)} if tr.enabled else None):
+            with tr.span("engine.h2d", cat="engine"):
+                tokens = jnp.asarray(self._next_tok)
+            nxt, self.kv_cache = self._decode(self.params, self.kv_cache, tokens)
+        self.stats.decode_s += time.perf_counter() - t0
         self.stats.steps += 1
-        nxt_np = np.asarray(nxt)
+        with tr.span("engine.readback", cat="engine", args=_DECODE_READBACK):
+            nxt_np = np.asarray(nxt)
         for s in live:
             req = self.slots[s]
             req.generated.append(int(nxt_np[s, 0]))

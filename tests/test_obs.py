@@ -17,6 +17,7 @@ Covers the tentpole and satellite 3:
 """
 
 import json
+import sys
 import threading
 import time
 
@@ -32,6 +33,7 @@ from repro.obs import (
     MetricsRegistry,
     Sample,
     SpanTracer,
+    StallWatch,
     register_cache,
     register_dispatch,
     register_tracer,
@@ -40,6 +42,8 @@ from repro.obs import (
     worker_overlap,
     write_chrome_trace,
 )
+
+from repro.obs import tracer as tracer_mod
 
 from _fakes import SeqEngine
 
@@ -123,6 +127,172 @@ class TestTracerCore:
     def test_buffer_size_validation(self):
         with pytest.raises(ValueError):
             SpanTracer(buffer_size=0)
+
+
+# -- scoped spans and the host-stall watch ----------------------------------
+
+
+def _watch_threads() -> list:
+    return [t for t in threading.enumerate() if t.name == "repro-obs-stall-watch"]
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts what is built."""
+
+    built: list = []
+
+    def __init__(self, name, **meta):
+        _CountingAnnotation.built.append((name, meta))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+class TestScopedSpans:
+    def test_disabled_span_is_the_shared_noop(self, monkeypatch):
+        monkeypatch.setattr(tracer_mod, "_ANNOTATION", _CountingAnnotation)
+        _CountingAnnotation.built = []
+        tr = SpanTracer()
+        before = len(_watch_threads())
+        sp = tr.span("decode", cat="engine", rid=3)
+        assert sp is tracer_mod.NULL_SPAN
+        assert tr.span("prefill", cat="engine") is sp
+        with sp as inner:
+            assert not inner                   # falsy: `if span:` guards args
+        assert _CountingAnnotation.built == []
+        assert tr.watch is None and len(_watch_threads()) == before
+        assert tr.drain() == [] and tr.stats()["emitted"] == 0
+
+    def test_enabled_span_annotates_and_records(self, monkeypatch):
+        monkeypatch.setattr(tracer_mod, "_ANNOTATION", _CountingAnnotation)
+        _CountingAnnotation.built = []
+        tr = SpanTracer().enable()
+        try:
+            assert tr.watch is not None and tr.watch.running
+            with tr.span("step:m0", cat="step", lane="m0",
+                         args={"bucket": 64, "key": (1, 2)}) as sp:
+                assert sp
+                sp.args = {"tokens": 5}
+        finally:
+            tr.disable()
+        assert tr.watch is None
+        assert _CountingAnnotation.built == [("step:m0", {"lane": "m0", "bucket": 64})]
+        (ev,) = [e for e in tr.drain() if e.ph == "X"]
+        assert (ev.name, ev.cat, ev.lane, ev.args) == ("step:m0", "step", "m0", {"tokens": 5})
+        assert ev.dur >= 0.0
+
+    def test_disable_stops_and_joins_the_watch(self):
+        tr = SpanTracer().enable()
+        watch = tr.watch
+        assert watch.running
+        tr.enable()                            # idempotent: the same watch
+        assert tr.watch is watch
+        tr.disable()
+        assert not watch.running
+        assert all(t is not watch._thread for t in _watch_threads())
+
+    @pytest.mark.timeout(120)
+    def test_span_lands_in_a_cpu_profile(self, tmp_path):
+        jax = pytest.importorskip("jax")
+        from jax.profiler import ProfileData
+
+        tr = SpanTracer().enable()
+        try:
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                with tr.span("obs.test.span", cat="test", rid=11):
+                    time.sleep(0.02)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            tr.disable()
+        (ring,) = [e for e in tr.drain() if e.name == "obs.test.span"]
+        (path,) = list(tmp_path.glob("**/*.xplane.pb"))
+        profile = ProfileData.from_file(str(path))
+        host = [ev for plane in profile.planes if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name == "obs.test.span"]
+        assert len(host) == 1
+        assert host[0].duration_ns / 1e9 == pytest.approx(ring.dur, abs=1e-3)
+
+
+class _FakeClock:
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+
+class TestStallWatch:
+    def _traced(self):
+        tr = SpanTracer()
+        tr.enabled = True                      # recording, without a live watch
+        return tr
+
+    def test_one_stall_per_late_wake_none_on_time(self):
+        tr, clock = self._traced(), _FakeClock()
+        watch = StallWatch(tr, period_s=0.01, threshold_s=0.05, clock=clock)
+        for t in (0.010, 0.020, 0.030):        # on time
+            clock.now = t
+            assert watch.check() is None
+        clock.now = 0.030 + 0.010 + 0.080      # 80 ms late
+        assert watch.check() == pytest.approx(0.080)
+        clock.now += 0.010 + 0.049             # 49 ms late: below the threshold
+        assert watch.check() is None
+        clock.now += 0.010 + 0.300             # 300 ms late
+        assert watch.check() == pytest.approx(0.300)
+        stalls = [e for e in tr.drain() if e.name == "host.stall"]
+        assert [(e.ph, e.cat) for e in stalls] == [("X", "host")] * 2
+        assert stalls[0].ts == pytest.approx(0.030)
+        assert stalls[0].dur == pytest.approx(0.090)
+        assert stalls[1].dur == pytest.approx(0.310)
+        assert [e.args["late_ms"] for e in stalls] == [pytest.approx(80.0),
+                                                      pytest.approx(300.0)]
+        for key in ("cpu_ms", "minflt", "majflt", "nvcsw", "nivcsw", "top_threads"):
+            assert key in stalls[0].args
+
+    def test_lag_counter_once_a_second(self):
+        tr, clock = self._traced(), _FakeClock()
+        watch = StallWatch(tr, period_s=0.01, threshold_s=0.05, clock=clock)
+        for i in range(100):                   # a second of wakes, on time
+            clock.now += 0.010 + (0.020 if i == 40 else 0.0)   # but one
+            assert watch.check() is None
+        (lag,) = [e for e in tr.drain() if e.name == "host.lag_ms"]
+        assert lag.ph == "C" and lag.args == {"ms": pytest.approx(20.0)}
+
+    def test_bad_period_or_threshold(self):
+        with pytest.raises(ValueError):
+            StallWatch(SpanTracer(), period_s=0.0)
+        with pytest.raises(ValueError):
+            StallWatch(SpanTracer(), threshold_s=-1.0)
+
+    @pytest.mark.timeout(60)
+    def test_a_gil_hold_is_a_stall_charged_to_its_holder(self):
+        tr = SpanTracer().enable()
+        old = sys.getswitchinterval()
+        try:
+            time.sleep(0.15)                   # the watch is asleep, sampled
+            sys.setswitchinterval(1.0)
+            end = time.perf_counter() + 0.4
+            while time.perf_counter() < end:   # holds the GIL throughout
+                pass
+        finally:
+            sys.setswitchinterval(old)
+        time.sleep(0.1)                        # the watch wakes and records
+        tr.disable()
+        stalls = [e for e in tr.drain() if e.name == "host.stall"]
+        assert stalls
+        worst = max(stalls, key=lambda e: e.dur)
+        assert worst.dur >= 0.3 and worst.args["late_ms"] >= 250
+        name, tid, cpu_ms = worst.args["top_threads"][0]
+        me = threading.current_thread()
+        assert (name, tid) == (me.name, me.native_id)
+        # the holder ran through the stall (less on an oversubscribed host)
+        assert cpu_ms >= 0.25 * worst.args["late_ms"]
 
 
 # -- export -----------------------------------------------------------------

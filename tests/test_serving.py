@@ -256,3 +256,49 @@ def test_dispatcher_matches_direct_engine(model, shared_cache):
     got = {r.rid: r.generated for r in disp.run_until_drained()}
     assert got == ref
     assert disp.snapshot()["requests_done"] == 5
+
+
+def test_engine_spans_bracket_the_host_work(model, shared_cache):
+    """Each admission opens prefill (with engine.h2d inside it), then
+    engine.readback; each decode step opens decode (h2d inside), then its
+    readback; each finish opens engine.finish.  t_first comes after the
+    first token's read-back."""
+    from repro.obs import SpanTracer
+
+    cfg, _ = model
+    tr = SpanTracer().enable()
+    try:
+        eng = _engine(model, shared_cache, tracer=tr)
+        reqs = _reqs(cfg, 2, max_new=3)
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+    finally:
+        tr.disable()
+    spans = [e for e in tr.drain() if e.ph == "X" and e.cat == "engine"]
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e.name, []).append(e)
+    assert len(by_name["prefill"]) == 2 and len(by_name["engine.finish"]) == 2
+    assert len(by_name["decode"]) >= 2
+    assert len(by_name["engine.h2d"]) == len(by_name["prefill"]) + len(by_name["decode"])
+    kinds = [e.args["kind"] for e in by_name["engine.readback"]]
+    assert kinds.count("prefill") == 2 and kinds.count("decode") == len(by_name["decode"])
+    for outer in by_name["prefill"] + by_name["decode"]:
+        inner = [h for h in by_name["engine.h2d"]
+                 if outer.ts <= h.ts and h.ts + h.dur <= outer.ts + outer.dur]
+        assert len(inner) == 1
+    assert {e.rid for e in by_name["engine.finish"]} == {r.rid for r in reqs}
+    reads = [e for e in by_name["engine.readback"] if e.args["kind"] == "prefill"]
+    for req, pre, rb in zip(reqs, by_name["prefill"], reads):
+        assert pre.rid == req.rid
+        assert pre.ts + pre.dur <= rb.ts
+        assert req.t_first >= rb.ts + rb.dur
+
+
+def test_sealed_programs_carry_their_body_names(model, shared_cache):
+    """The device trace names a run after its program: jit_decode_body and
+    jit_prefill_body, not jit__unknown."""
+    eng = _engine(model, shared_cache)
+    assert eng._decode.as_text().startswith("HloModule jit_decode_body")
+    assert eng._get_prefill_exec(8).as_text().startswith("HloModule jit_prefill_body")
