@@ -250,10 +250,9 @@ def attention(
     if cache is not None and not update_cache:
         # Deferred append: attend against the read-only cache plus the new
         # tokens *without* materializing an updated cache — the caller
-        # performs ONE donated dynamic-update-slice for all layers after the
-        # layer scan, which XLA can alias in place (the per-layer update
-        # inside a scan cannot be elided and costs a full cache copy per
-        # step; see EXPERIMENTS.md §Perf, decode hillclimb).
+        # writes all layers' new K/V after the layer scan (``append_kv``),
+        # in place when the cache is donated (the per-layer update inside
+        # a scan cannot be elided and costs a full cache copy per step).
         idx = jnp.broadcast_to(jnp.asarray(cache["pos"]), (B,)).astype(jnp.int32)
         out = _sdpa_deferred(
             q, cache["k"], cache["v"], k, v,
@@ -376,20 +375,21 @@ def _sdpa_deferred(q, k_cache, v_cache, k_new, v_new, *, scale, softcap_val,
 
 
 def append_kv(cache_k, cache_v, new_k, new_v, pos):
-    """One batched cache append for ALL layers (donation-friendly).
+    """Write every layer's new K/V into the cache at each slot's offset.
 
-    cache_k/v: (L,B,S,nkv,hd); new_k/v: (L,B,S_new,nkv,hd); pos: (B,)."""
-    def upd(c, u, i):
-        # c: (L,S,nkv,hd) one batch slot across layers
-        return jax.lax.dynamic_update_slice(c, u, (0, i, 0, 0))
-
-    ck = jax.vmap(upd, in_axes=(1, 1, 0), out_axes=1)(
-        cache_k, new_k.astype(cache_k.dtype), pos
-    )
-    cv = jax.vmap(upd, in_axes=(1, 1, 0), out_axes=1)(
-        cache_v, new_v.astype(cache_v.dtype), pos
-    )
-    return ck, cv
+    cache_k/v: (L,B,T,nkv,hd); new_k/v: (L,B,S_new,nkv,hd); pos: (B,).
+    One dynamic-update-slice per slot, over the static slot count, in the
+    cache's own dimension order: with the cache donated, XLA updates it in
+    place.  (A vmap over the slot axis compiles on TPU to a slot-major
+    transpose of the whole cache, a scatter, and a transpose back.)  Each
+    offset clamps to ``T - S_new``, as ``dynamic_update_slice`` does."""
+    new_k = new_k.astype(cache_k.dtype)
+    new_v = new_v.astype(cache_v.dtype)
+    for b in range(cache_k.shape[1]):
+        at = (0, b, pos[b], 0, 0)
+        cache_k = jax.lax.dynamic_update_slice(cache_k, new_k[:, b:b + 1], at)
+        cache_v = jax.lax.dynamic_update_slice(cache_v, new_v[:, b:b + 1], at)
+    return cache_k, cache_v
 
 
 def cross_attention(p: Params, x: jax.Array, memory: jax.Array, cfg) -> jax.Array:

@@ -540,8 +540,8 @@ def decode_step(p, cache, tokens, cfg):
             else:
                 lcache = {"k": ck, "v": cv, "pos": pos}
                 h = L.apply_norm(lp["ln1"], x, cfg)
-                # deferred append: read-only cache here; ONE donated update
-                # for all layers after the scan (see layers._sdpa_deferred)
+                # deferred append: read-only cache here; the new K/V of all
+                # layers are written after the scan (see layers.append_kv)
                 attn_out, (new_k, new_v) = L.attention(
                     lp["attn"], h, cfg, positions=positions, layer_window=w,
                     cache=lcache, update_cache=False,
@@ -572,7 +572,8 @@ def decode_step(p, cache, tokens, cfg):
             new_cache = {"ckv": new_kv[0], "krope": new_kv[1], "pos": cache["pos"] + S_new}
         else:
             if synced:
-                # ONE donated-aliasable update for all layers and slots
+                # one update for all layers and slots, in place when the
+                # cache is donated
                 ck = jax.lax.dynamic_update_slice(
                     cache["k"], new_kv[0].astype(cache["k"].dtype),
                     (0, 0, pos_raw, 0, 0))

@@ -151,6 +151,23 @@ def prefill_body(params, tokens, cache, slot, true_len, *, cfg):
     return nxt, new_cache
 
 
+# The step programs take the KV cache as a donated argument: each returns
+# the updated cache in the buffers of the one it was given, so the engine
+# must rebind ``kv_cache`` to every output and keep no other reference.
+DECODE_DONATE = (1,)
+PREFILL_DONATE = (2,)
+
+
+def decode_program(cfg):
+    """``decode_body`` for ``cfg``, jitted as the engine seals it."""
+    return jax.jit(_bind_cfg(decode_body, cfg), donate_argnums=DECODE_DONATE)
+
+
+def prefill_program(cfg):
+    """``prefill_body`` for ``cfg``, jitted as the engine seals it."""
+    return jax.jit(_bind_cfg(prefill_body, cfg), donate_argnums=PREFILL_DONATE)
+
+
 class ServingEngine:
     """AoT-scheduled batched serving for any registered architecture."""
 
@@ -293,8 +310,9 @@ class ServingEngine:
     def _exec_arena_bytes(self, *extra_shapes: tuple) -> int:
         """Reserved-memory estimate for one step executable, derived from
         its output buffer shapes: every step returns the full KV cache
-        (the dominant term — without donation XLA materializes a fresh
-        copy) plus the next-token array.  ``extra_shapes`` adds
+        (the dominant term; the step is sealed with the cache donated, so
+        that output aliases its input and this over-counts) plus the
+        next-token array.  ``extra_shapes`` adds
         ``(shape, dtype)`` pairs for per-executable outputs/temps (e.g. a
         prefill's padded token buffer).  Byte-budget eviction needs a
         non-zero number here: raw executables carry no TaskSchedule stats,
@@ -329,12 +347,12 @@ class ServingEngine:
             decode_step,
             (self.params, self.kv_cache,
              jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32)),
-            self._key_options,
+            self._key_options + (("donate_argnums", DECODE_DONATE),),
             fn_id=f"serving.decode/{self.cfg.name}",
         )
 
         def build():
-            exe = jax.jit(_bind_cfg(decode_body, self.cfg)).lower(
+            exe = decode_program(self.cfg).lower(
                 self.params, self.kv_cache,
                 jax.ShapeDtypeStruct((self.max_slots, 1), jnp.int32),
             ).compile()
@@ -361,7 +379,7 @@ class ServingEngine:
             (self.params,
              jax.ShapeDtypeStruct((1, bucket), jnp.int32),
              self.kv_cache),
-            self._key_options,
+            self._key_options + (("donate_argnums", PREFILL_DONATE),),
             fn_id=f"serving.prefill/{self.cfg.name}",
         )
         self._prefill_keys[bucket] = key
@@ -373,7 +391,7 @@ class ServingEngine:
         key = self._prefill_key(bucket)
 
         def build():
-            exe = jax.jit(_bind_cfg(prefill_body, self.cfg)).lower(
+            exe = prefill_program(self.cfg).lower(
                 self.params,
                 jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                 self.kv_cache,

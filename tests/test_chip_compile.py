@@ -11,7 +11,11 @@ a described chip cannot be read back without one).
 from __future__ import annotations
 
 import functools
+import json
+import math
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +26,9 @@ import repro.configs as C
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.stream_pack import stream_pack_matmul
 from repro.models import abstract_model, init_cache
-from repro.serving.engine import decode_body, prefill_body
+from repro.serving.engine import (
+    decode_body, decode_program, prefill_body, prefill_program,
+)
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 SLOTS, MAX_LEN, BUCKET = 8, 1024, 512
@@ -109,3 +115,59 @@ def test_stablelm_prefill_bucket_fits_one_chip(one_chip, stablelm):
         params, _sds((1, BUCKET), jnp.int32, one_chip), cache, scalar, scalar,
     ).compile()
     assert _footprint(compiled) < HBM_BYTES
+
+
+# --- the engine's sealed steps at the benchmark's engine sizes -------------
+
+BENCH_CONFIGS = Path(__file__).resolve().parents[1] / "bench" / "configs"
+# an instruction's name and the first array shape it produces
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = \(?\w+\[([\d,]*)\]")
+
+
+def _copies_of(compiled, elements: int) -> list:
+    """Names of ``copy`` / ``copy*fusion`` ops producing ``elements`` values."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = _HLO_OP.match(line)
+        if m and m.group(1).startswith("copy") and m.group(2):
+            if math.prod(int(d) for d in m.group(2).split(",")) == elements:
+                found.append(m.group(1))
+    return found
+
+
+@pytest.fixture(scope="module", params=["stablelm-1.6b", "phi4-mini-3.8b"])
+def served(request, one_chip):
+    """A benchmark configuration's params and KV cache, as shapes, at its
+    engine's slots and length."""
+    conf = json.loads((BENCH_CONFIGS / f"{request.param}.json").read_text())
+    cfg = C.ModelConfig(**conf["model"])
+    eng = conf["engine"]
+    params, _ = abstract_model(cfg)
+    cache = jax.eval_shape(
+        lambda: init_cache(cfg, eng["max_slots"], eng["max_len"]))
+    return cfg, eng, _on(params, one_chip), _on(cache, one_chip)
+
+
+def _assert_cache_updated_in_place(compiled, cache):
+    kv = (cache["k"], cache["v"])
+    for leaf in kv:
+        assert _copies_of(compiled, math.prod(leaf.shape)) == []
+    kv_bytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize for leaf in kv)
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+
+
+def test_sealed_decode_writes_the_cache_in_place(one_chip, served):
+    cfg, eng, params, cache = served
+    tokens = _sds((eng["max_slots"], 1), jnp.int32, one_chip)
+    compiled = decode_program(cfg).lower(params, cache, tokens).compile()
+    _assert_cache_updated_in_place(compiled, cache)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_sealed_prefill_writes_the_cache_in_place(one_chip, served):
+    cfg, eng, params, cache = served
+    scalar = _sds((), jnp.int32, one_chip)
+    tokens = _sds((1, max(eng["buckets"])), jnp.int32, one_chip)
+    compiled = prefill_program(cfg).lower(
+        params, tokens, cache, scalar, scalar).compile()
+    _assert_cache_updated_in_place(compiled, cache)

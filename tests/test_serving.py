@@ -9,7 +9,7 @@ import pytest
 
 import repro.configs as C
 from repro.dispatch import Dispatcher, ScheduleCache
-from repro.models import init_model
+from repro.models import forward, init_model
 from repro.serving import Request, ServingEngine
 
 
@@ -256,6 +256,44 @@ def test_dispatcher_matches_direct_engine(model, shared_cache):
     got = {r.rid: r.generated for r in disp.run_until_drained()}
     assert got == ref
     assert disp.snapshot()["requests_done"] == 5
+
+
+def _greedy_reference(model, prompt, max_new, pad_to=32):
+    """Greedy tokens from full-sequence forwards over the growing sequence
+    (padded to one length: attention is causal, so padding is not seen)."""
+    cfg, params = model
+    fwd = jax.jit(lambda p, t: forward(p, {"tokens": t}, cfg)[0])
+    seq, out = list(prompt), []
+    for _ in range(max_new):
+        tokens = np.zeros((1, pad_to), np.int32)
+        tokens[0, :len(seq)] = seq
+        logits = np.asarray(fwd(params, tokens))[0, len(seq) - 1, : cfg.vocab]
+        out.append(int(np.argmax(logits)))
+        seq.append(out[-1])
+    return out
+
+
+def test_step_donates_the_previous_cache(model, shared_cache):
+    """Each step's programs take the KV cache as a donated argument: the
+    buffers the engine held before ``step()`` are deleted after it, and
+    the greedy tokens still match full-sequence forwards."""
+    cfg, _ = model
+    eng = _engine(model, shared_cache)
+    reqs = _reqs(cfg, 3, max_new=5, seed=4)
+    reqs[1].prompt = reqs[1].prompt[:3]           # slots at other offsets
+    for r in reqs:
+        eng.submit(r)
+    steps = 0
+    while not eng.idle:
+        before = jax.tree_util.tree_leaves(eng.kv_cache)
+        eng.step()
+        steps += 1
+        assert all(leaf.is_deleted() for leaf in before)
+        assert not any(
+            leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(eng.kv_cache))
+    assert steps > 1
+    for r in reqs:
+        assert r.generated == _greedy_reference(model, r.prompt, r.max_new_tokens)
 
 
 def test_engine_spans_bracket_the_host_work(model, shared_cache):
