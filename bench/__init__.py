@@ -5,6 +5,7 @@ per run, driven through the program's normal serving path.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric lives in a file of its own, found by the name ``BENCHMARK.json``
-gives it: ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``
-and ``bench/metrics/<metric>.py``.
+gives it: ``bench/configs/<config>.json`` (whose ``"arch"`` names
+``bench/arch/<arch>.py``), ``bench/traffic/<traffic>.json`` and
+``bench/metrics/<metric>.py``.
 """

@@ -2,16 +2,17 @@
 
 Once the window has closed and the engine's state is freed, a sample of
 the requests finished in the window, drawn from the seed and always
-holding the longest, is run through :mod:`bench.reference` in float32
-over each prompt and its served tokens.  At every served position the gap
-by which the served token's reference logit lies below the reference's
-best is read; the widest gap is compared with the configuration's limit.
+holding the longest, is run through the configuration's plain reference
+(its architecture module's ``logits_at``) in float32 over each prompt and
+its served tokens.  At every served position the gap by which the served
+token's reference logit lies below the reference's best is read; the
+widest gap is compared with the configuration's limit.
 The served path decodes greedily, so a sound run reads gaps only where
 bfloat16 rounding flips a near tie.
 
-The control puts the reference in the program's place at float8 (see
-:mod:`bench.reference`): at the same positions it reads the gap of the
-token that float8 puts first.
+The control puts the reference in the program's place at float8
+(``mode="fp8"``, see :mod:`bench.reference`): at the same positions it
+reads the gap of the token that float8 puts first.
 """
 
 from __future__ import annotations
@@ -19,27 +20,35 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference
-
 SAMPLE_SALT = 0x5A3C_9E11      # the sample's stream, apart from the traffic's
 
 
 def sample(done: list, seed: int, target_tokens: int, max_requests: int) -> list:
-    """Finished requests (``bench.serve.Sent``) to compare: the longest,
-    then others in a seeded order until ``target_tokens`` served tokens
-    or ``max_requests`` requests."""
+    """Finished requests (``bench.serve.Sent``) to compare: the longest and,
+    whatever the target, the first request in a seeded order of each lane
+    the longest did not come from, so that a fault in one replica of
+    several cannot pass unread; then others in that order until
+    ``target_tokens`` served tokens or ``max_requests`` requests.  A
+    one-lane sample is the longest, then the seeded order."""
     if not done:
         return []
     size = lambda s: len(s.req.prompt) + len(s.req.generated)
     longest = max(done, key=size)
     rest = [s for s in done if s is not longest]
     order = np.random.default_rng((seed ^ SAMPLE_SALT) % 2**64).permutation(len(rest))
-    picks, tokens = [longest], len(longest.req.generated)
+    seen, ahead = {longest.lane}, set()
+    for i in order:
+        if rest[i].lane not in seen:
+            seen.add(rest[i].lane)
+            ahead.add(i)
+    picks = [longest] + [rest[i] for i in order if i in ahead]
+    tokens = sum(len(s.req.generated) for s in picks)
     for i in order:
         if tokens >= target_tokens or len(picks) >= max_requests:
             break
-        picks.append(rest[i])
-        tokens += len(rest[i].req.generated)
+        if i not in ahead:
+            picks.append(rest[i])
+            tokens += len(rest[i].req.generated)
     return picks
 
 
@@ -66,15 +75,16 @@ def _gaps(logits, chosen) -> np.ndarray:
     return np.asarray(best - at)
 
 
-def widest_gap(params: dict, model: dict, picks: list, max_len: int,
+def widest_gap(arch, params: dict, model: dict, picks: list, max_len: int,
                control: bool = False) -> dict:
-    """The widest gap under the float32 reference of the served tokens or,
-    with ``control``, of the tokens that the float8 control puts first at
-    the same positions; and what was compared."""
+    """The widest gap under the float32 reference of ``arch`` (the
+    configuration's architecture module) of the served tokens or, with
+    ``control``, of the tokens that its float8 control puts first at the
+    same positions; and what was compared."""
     toks, rows, cols, served = positions(picks, max_len)
-    logits = reference.logits_at(params, model, toks, rows, cols)
+    logits = arch.logits_at(params, model, toks, rows, cols)
     if control:
-        low = reference.logits_at(params, model, toks, rows, cols, mode="fp8")
+        low = arch.logits_at(params, model, toks, rows, cols, mode="fp8")
         served = np.asarray(jnp.argmax(low, axis=-1))
     gaps = _gaps(logits, served)
     return {"gap_max": float(gaps.max()), "tokens": int(len(served)),

@@ -130,7 +130,7 @@ class Context:
     """What a per-layer metric reads (see ``bench/metrics/``)."""
 
     cell: object
-    shapes: object           # bench.work.Shapes
+    shapes: object           # the architecture module's Shapes
     peaks: object            # bench.peaks.Peaks
     window: Window
     sent: list               # [bench.serve.Sent]
@@ -197,7 +197,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
     sets it), so ``correct`` should read false."""
     import jax
 
-    from bench import check, serve, work
+    from bench import check, serve
     from bench import trace as trace_mod
     from bench.peaks import peaks_for
     from repro.obs.tracer import get_tracer
@@ -261,7 +261,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
         tracer.disable()
     win = Window(w0, w1, tokens0, tokens1, grants, compiles)
     sent = list(load.sent)
-    memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
+    peaks_by_device = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices]
+    memory_peak = max(peaks_by_device)
+    log(f"memory peak per device: {peaks_by_device} B")
     records = {r._lane: list(r.records) for r in served.recorders}
     lane_device = {lane: d.id for lane, d in zip(served.lanes, devices)}
     params = served.params[0]
@@ -283,7 +285,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
 
     _log_load(sent, w0, w1)
     log(gc_pauses.summary(w0, w1))
-    shapes = work.Shapes.from_model(cell.model)
+    shapes = cell.arch.Shapes.from_model(cell.model)
     if trace:
         tr = trace_mod.reduce(_xplane(trace_dir))
         ctx = Context(cell, shapes, peaks, win, sent, records, lane_device, spans, tr)
@@ -298,10 +300,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
     chk = cell.config["check"]
     picks = check.sample(done, seed, chk["sample_tokens"], chk["sample_requests"])
     faults = check.complete(picks, cell.model["vocab"])
-    gap = (check.widest_gap(params, cell.model, picks, cell.engine["max_len"], control)
+    gap = (check.widest_gap(cell.arch, params, cell.model, picks, cell.engine["max_len"],
+                            control)
            if picks else {"gap_max": float("inf"), "tokens": 0, "requests": 0})
     correct = bool(picks) and not faults and gap["gap_max"] <= chk["max_logit_gap"]
-    log(f"reference over {gap['requests']} requests, {gap['tokens']} served tokens, "
+    lanes = len({s.lane for s in picks})
+    log(f"reference over {gap['requests']} requests of {lanes} lanes, "
+        f"{gap['tokens']} served tokens, "
         f"in {time.perf_counter() - t_check:.1f}s")
     for f in faults[:10]:
         log(f"fault: {f}")
@@ -317,7 +322,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
         "memory_peak_bytes": memory_peak,
         "lateness": late,
         "compiles_in_window": compiles,
-        "checked": {"requests": gap["requests"], "tokens": gap["tokens"]},
+        "checked": {"requests": gap["requests"], "tokens": gap["tokens"],
+                    "lanes": lanes},
         "compared": compared,
     }
     if tr is not None:

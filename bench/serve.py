@@ -16,6 +16,7 @@ import contextlib
 import dataclasses
 import threading
 import time
+import typing
 from typing import Optional
 
 import jax
@@ -154,15 +155,35 @@ def used_buckets(cell) -> tuple:
     return tuple(b for b in buckets if first <= b <= last)
 
 
+def _typed(hint, value):
+    """``value`` from a JSON file as the type ``hint`` names: a dict as the
+    dataclass it names (``Optional[...]`` unwrapped), a list as a tuple."""
+    if isinstance(value, dict):
+        for cls in (hint, *typing.get_args(hint)):
+            if dataclasses.is_dataclass(cls):
+                return _dataclass(cls, value)
+    if isinstance(value, list) and typing.get_origin(hint) is tuple:
+        return tuple(value)
+    return value
+
+
+def _dataclass(cls, fields: dict):
+    hints = typing.get_type_hints(cls)
+    return cls(**{k: _typed(hints[k], v) for k, v in fields.items()})
+
+
 def model_config(model: dict) -> ModelConfig:
-    """The program's configuration object, every field pinned by the file."""
-    return ModelConfig(**model)
+    """The program's configuration object, every field pinned by the file;
+    a nested section (``moe``, ``mla``, ...) becomes the dataclass that
+    ``ModelConfig``'s field names, ``None`` stays ``None``."""
+    return _dataclass(ModelConfig, model)
 
 
 def build(cell, seed: int, devices: list, annotate: bool) -> Served:
-    """Weights per device from ``seed``, one sealed engine per device (only
-    the buckets this cell's prompts use), one pool dispatcher over them,
-    and one warm-up request per bucket per engine."""
+    """Weights per device from ``seed`` in the layout of the configuration's
+    architecture module, one sealed engine per device (only the buckets
+    this cell's prompts use), one pool dispatcher over them, and one
+    warm-up request per bucket per engine."""
     eng_cfg = cell.engine
     buckets = used_buckets(cell)
     generator.check_fits(cell.traffic, eng_cfg["max_len"], buckets)
@@ -170,7 +191,7 @@ def build(cell, seed: int, devices: list, annotate: bool) -> Served:
     cache = ScheduleCache(capacity=64)
     params, engines, recorders, lanes = [], [], [], []
     for i, dev in enumerate(devices):
-        p = weights.make(cell.model, seed, dev)
+        p = weights.make(cell.arch.layout(cell.model), seed, dev)
         eng = ServingEngine(
             cfg, p, max_slots=eng_cfg["max_slots"], max_len=eng_cfg["max_len"],
             bucketing=buckets, schedule_cache=cache, device=dev,

@@ -39,13 +39,15 @@ def closed_mix() -> dict:
 
 def write_root(root: Path, mixes: dict, model: dict = None) -> Path:
     """BENCHMARK.json with one smoke configuration and one cell per mix
-    (``{cell name: mix}``), plus the committed metric readers."""
+    (``{cell name: mix}``), plus the committed metric readers and
+    architecture modules."""
     root = Path(root)
     (root / "bench/configs").mkdir(parents=True, exist_ok=True)
     (root / "bench/traffic").mkdir(parents=True, exist_ok=True)
-    if not (root / "bench/metrics").exists():
-        shutil.copytree(REPO / "bench/metrics", root / "bench/metrics")
-    config = {"name": "smoke", "model": model or smoke_model(),
+    for d in ("bench/metrics", "bench/arch"):
+        if not (root / d).exists():
+            shutil.copytree(REPO / d, root / d, ignore=shutil.ignore_patterns("__pycache__"))
+    config = {"name": "smoke", "arch": "dense", "model": model or smoke_model(),
               "engine": {"max_slots": 4, "max_len": 128, "buckets": [16, 32, 64]},
               "check": {"max_logit_gap": SMOKE_LIMIT, "sample_tokens": 96,
                         "sample_requests": 12}}

@@ -18,7 +18,7 @@ import _bench_smoke as S
 import jax
 import pytest
 
-from bench import run, serve, spec
+from bench import run, serve, spec, work
 from bench.peaks import PEAKS
 
 pytestmark = pytest.mark.timeout(300)
@@ -88,6 +88,113 @@ def test_new_config_mix_cell_and_metric_need_no_harness_edit(tmp_path):
     assert run.per_layer(ctx, root) == {"finished_share": {"value": 50.0, "unit": "%"}}
     ctx.sent = []
     assert run.per_layer(ctx, root) == {}
+
+
+# An architecture module written beside a configuration, in the cell's
+# root only: the dense one underneath, with every call recorded, the
+# counts doubled (so readings show whose counts they are) and the
+# reference's logits breakable.
+RECORDED_ARCH = '''
+from bench.arch import dense
+
+CALLS = []
+BREAK = False
+
+
+def layout(model):
+    CALLS.append("layout")
+    return dense.layout(model)
+
+
+def logits_at(params, model, tokens, rows, cols, mode="float32", block=4):
+    CALLS.append("logits_at:" + mode)
+    out = dense.logits_at(params, model, tokens, rows, cols, mode=mode, block=block)
+    return out.at[:, 0].add(1e3) if BREAK else out
+
+
+class Shapes(dense.Shapes):
+    def decode(self, positions):
+        CALLS.append("decode")
+        return tuple(2 * x for x in super().decode(positions))
+
+    def prefill(self, prompt_len):
+        CALLS.append("prefill")
+        return tuple(2 * x for x in super().prefill(prompt_len))
+'''
+
+
+def _add_config(root: Path, name: str, cell: str, **changes) -> None:
+    cfg = json.loads((root / "bench/configs/smoke.json").read_text())
+    cfg.update(name=name, **changes)
+    (root / f"bench/configs/{name}.json").write_text(json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": name, "source": "test",
+                             "file": f"bench/configs/{name}.json", "reduced": [], "why": "t"})
+    bench["workloads"].append({"name": cell, "config": name, "traffic": "chat",
+                               "chips": 1, "why": "t"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_new_architecture_joins_as_a_file_with_no_harness_edit(tmp_path):
+    from test_bench_trace import DEVICE, HOST, _xspace
+
+    from bench import trace
+    from bench.arch import dense
+    from jax.profiler import ProfileData
+
+    root = S.write_root(tmp_path, {"chat": S.open_mix()})
+    (root / "bench/arch/recorded.py").write_text(RECORDED_ARCH)
+    _add_config(root, "smoke-rec", "rec-chat", arch="recorded")
+    cell = spec.load_cell("rec-chat", root)
+    mod = spec.arch(cell, root)
+    assert cell.arch is mod and mod.__file__ == str((root / "bench/arch/recorded.py").resolve())
+
+    # its layout made the weights and its reference decided ``correct``
+    res = _run(root, "rec-chat")
+    assert res["correct"], res["compared"]
+    assert mod.CALLS.count("layout") == 1 and "logits_at:float32" in mod.CALLS
+    mod.BREAK = True
+    try:
+        broken = _run(root, "rec-chat")
+    finally:
+        mod.BREAK = False
+    assert broken["correct"] is False
+    assert broken["compared"]["logit_gap_max"]["value"] > 100
+
+    # its Shapes feed the readers, as run_cell hands them over
+    shapes = cell.arch.Shapes.from_model(cell.model)
+    plain = dense.Shapes.from_model(cell.model)
+    text = _xspace({"/device:TPU:0": DEVICE, "/host:CPU": HOST})
+    tr = trace.reduce_profile(ProfileData.from_text_proto(text.replace('"python "', '"python"')))
+    rec = lambda i, pre, pos, t0: SimpleNamespace(index=i, prefills=pre, positions=pos,
+                                                  t0=t0, t1=t0 + 0.5)
+    records = {"replica0": [rec(0, [60], [100, 59], 1.0), rec(1, [], [101, 60], 2.0)]}
+    steps = [SimpleNamespace(ph="X", name="step:replica0", ts=t, dur=0.5) for t in (1.0, 2.0)]
+    peaks = PEAKS["TPU v5 lite"]
+    ctx = run.Context(cell, shapes, peaks, run.Window(0.0, 10.0, 0, 0, [], 0), [],
+                      records, {"replica0": 0}, steps, tr)
+    del mod.CALLS[:]
+    got = run.per_layer(ctx, root)
+    assert {"decode", "prefill"} <= set(mod.CALLS)
+    need = sum(work.roofline_seconds(*(2 * x for x in plain.decode(p)), peaks)
+               for p in ([100, 59], [101, 60]))
+    assert got["decode_roofline"]["value"] == pytest.approx(100 * need / 400e-6)
+    flops = 2 * (plain.decode([100, 59])[0] + plain.prefill(60)[0] + plain.decode([101, 60])[0])
+    assert got["mfu_pct"]["value"] == pytest.approx(100 * flops / (peaks.bf16_flops * 1.0))
+
+
+@pytest.mark.parametrize("arch", [None, "no_such_arch", "../configs/smoke"])
+def test_a_configuration_without_its_architecture_module_fails_in_load_cell(tmp_path, arch):
+    root = S.write_root(tmp_path, {"chat": S.open_mix()})
+    changes = {"arch": arch} if arch else {}
+    _add_config(root, "smoke-bad", "bad-chat", **changes)
+    if arch is None:
+        cfg = json.loads((root / "bench/configs/smoke-bad.json").read_text())
+        del cfg["arch"]
+        (root / "bench/configs/smoke-bad.json").write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match="'smoke-bad'"):
+        spec.load_cell("bad-chat", root)
+    assert spec.load_cell("chat", root).arch.Shapes is not None
 
 
 def test_per_layer_ttft_reads_what_the_end_to_end_metric_reads():

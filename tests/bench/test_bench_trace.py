@@ -3,6 +3,7 @@ out as an XSpace text proto: what every number should be is known."""
 
 from __future__ import annotations
 
+import re
 from types import SimpleNamespace
 
 import _bench_smoke as S
@@ -97,7 +98,7 @@ def test_idle_gaps_are_labelled_by_the_host(tr):
 
 def test_device_readers_on_the_trace(tr):
     cell = spec.load_cell("stablelm-chat")
-    shapes = work.Shapes.from_model(cell.model)
+    shapes = cell.arch.Shapes.from_model(cell.model)
     peaks = PEAKS["TPU v5 lite"]
     rec = lambda i, pre, pos: SimpleNamespace(index=i, prefills=pre, positions=pos)
     records = {"replica0": [rec(0, [60], [100, 59]), rec(1, [], [101, 60])]}
@@ -128,6 +129,49 @@ def test_a_run_ending_just_past_its_step_still_belongs_to_it():
     assert [(k, r.start) for k, r, _ in ctx.sealed_runs()] == [
         ("prefill", 10_000.0), ("decode", 200_000.0), ("decode", 600_000.0)]
     assert spec.reader("decode_step_ms")(ctx) == pytest.approx(0.2)
+
+
+def test_four_replicas_read_per_lane_and_per_chip():
+    # four lanes, each stepping on its own chip at the same times; chip d's
+    # decode runs last 200 + 20 d us, so a lane read on another lane's
+    # chip would move the mean.  Busy and idle are averaged over the chips.
+    planes, host = {}, {"python": [("bench.window", 0, 1000, {})]}
+    for d in range(4):
+        dec = 200 + 20 * d
+        runs = [(PREFILL, 10, 100), (DECODE, 200, dec), (DECODE, 600, dec)]
+        planes[f"/device:TPU:{d}"] = {
+            "XLA Modules": [(n, t, u, {}) for n, t, u in runs],
+            "XLA Ops": [("%fusion.1 = bf16[4,1,128]{2,1,0} fusion(%p)", t, u, {})
+                        for _, t, u in runs]}
+        # lane d reads back over 110 + 20 d .. 130 + 20 d, idle on its own
+        # chip (between its prefill and decode); lane 0 puts inputs over
+        # 150..190, and a put outside any step (960..990) counts on every chip
+        host["python" + " " * (d + 1)] = [
+            ("bench.step", 0, 480, {"lane": f"replica{d}", "step": 0}),
+            ("engine.readback", 110 + 20 * d, 20, {}),
+            ("bench.step", 500, 450, {"lane": f"replica{d}", "step": 1})] + (
+            [("engine.h2d", 150, 40, {})] if d == 0 else [])
+    host["python"].append(("engine.h2d", 960, 30, {}))
+    text = re.sub(r'name: "python +"', 'name: "python"', _xspace({**planes, "/host:CPU": host}))
+    tr4 = trace.reduce_profile(ProfileData.from_text_proto(text))
+    cell = spec.load_cell("stablelm-chat")
+    shapes, peaks = cell.arch.Shapes.from_model(cell.model), PEAKS["TPU v5 lite"]
+    rec = lambda i, pre, pos: SimpleNamespace(index=i, prefills=pre, positions=pos)
+    lanes = {f"replica{d}": d for d in range(4)}
+    records = {lane: [rec(0, [60], [100, 59]), rec(1, [], [101, 60])] for lane in lanes}
+    ctx = run.Context(cell, shapes, peaks, None, [], records, lanes, [], tr4)
+    assert len(ctx.runs("decode")) == 8 and len(ctx.runs("prefill")) == 4
+    read = lambda name: spec.reader(name)(ctx)
+    assert read("decode_step_ms") == pytest.approx(0.23)          # mean of 200..260 us
+    assert read("prefill_ms") == pytest.approx(0.1)
+    assert tr4.busy_s() == pytest.approx(560e-6)                  # 100 + 2 (200 + 20 d), mean
+    assert read("device_idle_pct") == pytest.approx(44.0)
+    need = 4 * sum(work.roofline_seconds(*shapes.decode(p), peaks) for p in ([100, 59], [101, 60]))
+    assert read("decode_roofline") == pytest.approx(100 * need / 1840e-6)
+    # each span counts against its own lane's chip: 20 us on each (2%), not
+    # the 80 us that all four lanes' spans cover together
+    assert read("idle_readback_pct") == pytest.approx(2.0)
+    assert read("idle_h2d_pct") == pytest.approx((40 / 4 + 30) / 10)
 
 
 def test_a_trace_without_the_window_or_a_device_is_refused():

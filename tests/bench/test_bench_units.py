@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import generator, reference, serve, spec, weights, work
+from bench import generator, serve, spec, weights, work
+from bench.arch import dense
 from bench.peaks import PEAKS, peaks_for
 from repro.configs.base import ModelConfig
 from repro.models import forward, init_model
@@ -32,17 +33,17 @@ def test_param_bytes_match_init_model(tie, norm):
     model["norm"] = norm
     cfg = ModelConfig(**model)
     params, _ = init_model(jax.random.key(0), cfg)
-    assert work.Shapes.from_model(model).param_bytes() == _leaf_bytes(params)
+    assert dense.Shapes.from_model(model).param_bytes() == _leaf_bytes(params)
 
 
 @pytest.mark.parametrize("tie", [False, True])
 def test_bench_weights_have_the_engine_layout(tie):
     model = S.smoke_model(tie)
-    made = weights.make(model, 2**40 + 3)
+    made = weights.make(dense.layout(model), 2**40 + 3)
     want, _ = abstract_model(ModelConfig(**model))
     shape = lambda t: jax.tree.map(lambda x: (x.shape, x.dtype), t)
     assert shape(made) == shape(want)
-    again = weights.make(model, 2**40 + 3)
+    again = weights.make(dense.layout(model), 2**40 + 3)
     assert all(bool(jnp.array_equal(a, b)) for a, b in
                zip(jax.tree_util.tree_leaves(made), jax.tree_util.tree_leaves(again)))
 
@@ -77,7 +78,7 @@ def test_step_timed_at_its_bound_reads_100(flops, nbytes):
 
 
 def test_decode_and_prefill_counts():
-    s = work.Shapes.from_model(S.smoke_model())
+    s = dense.Shapes.from_model(S.smoke_model())
     assert s.decode([]) == (0, 0)
     f1, b1 = s.decode([10])
     f2, b2 = s.decode([10, 20])
@@ -94,13 +95,13 @@ def test_decode_and_prefill_counts():
 def test_reference_matches_the_program_in_float32(tie):
     model = dict(S.smoke_model(tie), dtype="float32")
     cfg = ModelConfig(**model)
-    params = weights.make(model, 11)
+    params = weights.make(dense.layout(model), 11)
     toks = np.random.default_rng(0).integers(0, cfg.vocab, (3, 24)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         want, _ = forward(params, {"tokens": jnp.asarray(toks)}, cfg)
     want = np.asarray(want[..., : cfg.vocab])
     rows, cols = np.repeat(np.arange(3), 24), np.tile(np.arange(24), 3)
-    got = np.asarray(reference.logits_at(params, model, toks, rows, cols, block=2))
+    got = np.asarray(dense.logits_at(params, model, toks, rows, cols, block=2))
     np.testing.assert_allclose(got.reshape(want.shape), want, atol=1e-4, rtol=1e-4)
 
 
@@ -130,7 +131,8 @@ def test_a_fixed_order_leaves_only_the_tokens_to_the_seed():
     assert [x.prompt.tolist() for x in a] != [x.prompt.tolist() for x in b]
 
 
-@pytest.mark.parametrize("cell", ["stablelm-chat", "phi4mini-batch", "stablelm-rag"])
+@pytest.mark.parametrize("cell", ["stablelm-chat", "phi4mini-batch", "stablelm-rag",
+                                  "stablelm-chat-x4"])
 def test_committed_mixes_stay_in_their_clips_and_fit(cell):
     c = spec.load_cell(cell)
     mix = c.traffic
