@@ -1,3 +1,5 @@
-from .base import ARCH_IDS, ARCHS, ModelConfig, all_archs, get
+from .base import (ARCH_IDS, ARCHS, MLAConfig, ModelConfig, MoEConfig, RopeScaling,
+                   all_archs, get)
 
-__all__ = ["ARCH_IDS", "ARCHS", "ModelConfig", "all_archs", "get"]
+__all__ = ["ARCH_IDS", "ARCHS", "MLAConfig", "ModelConfig", "MoEConfig", "RopeScaling",
+           "all_archs", "get"]

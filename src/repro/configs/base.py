@@ -3,7 +3,7 @@
 Every assigned architecture gets one module in this package defining
 ``full_config()`` (the exact published configuration, used only via the
 dry-run — ShapeDtypeStruct, no allocation) and ``smoke_config()`` (a reduced
-same-family variant: ≤2 layers, d_model ≤ 512, ≤4 experts — runnable on CPU).
+same-family variant: ≤2 layers, d_model ≤ 512, ≤8 experts — runnable on CPU).
 
 Select with ``--arch <id>`` in the launchers; ``repro.configs.get(name)``.
 """
@@ -17,6 +17,13 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts.  The router scores all ``num_experts``; routing is
+    softmax, then (``n_group`` > 1) DeepSeek-V2's ``group_limited_greedy``:
+    the ``topk_group`` groups with the highest expert score are kept and
+    top-k is taken over their experts.  Gates are renormalised when
+    ``norm_topk_prob``, else multiplied by ``routed_scaling_factor``.
+    ``experts_held`` = (first, count) is this chip's share of the experts
+    under expert parallelism; () holds all of them."""
     num_experts: int
     top_k: int
     d_ff_expert: int
@@ -24,6 +31,16 @@ class MoEConfig:
     d_ff_shared: int = 0            # 0 => no dense/shared branch
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.01
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    experts_held: tuple[int, ...] = ()
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the experts whose weights live here."""
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +51,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN RoPE (arXiv:2309.00071), as DeepSeek-V2 configures it; read by
+    multi-head latent attention."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +96,7 @@ class ModelConfig:
     head_dim: int = 0               # 0 => d_model // n_heads
     # positional / attention details
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     sliding_window: int = 0         # 0 => full attention
     local_global_pattern: int = 0   # gemma2: every k-th layer global, rest local
     attn_softcap: float = 0.0
@@ -81,6 +111,9 @@ class ModelConfig:
     qk_norm: bool = False
     # sub-family configs
     moe: Optional[MoEConfig] = None
+    # moe: the first layers have a dense FFN of this width instead
+    first_dense_layers: int = 0
+    first_dense_d_ff: int = 0
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
@@ -149,12 +182,13 @@ class ModelConfig:
             m = self.mla
             attn = d * (m.kv_lora_rank + m.qk_rope_head_dim)
             attn += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
-            attn += d * self.n_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+            q_out = self.n_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+            attn += (d * m.q_lora_rank + m.q_lora_rank * q_out) if m.q_lora_rank else d * q_out
             attn += self.n_heads * m.v_head_dim * d
         else:
             attn = d * self.n_heads * h + 2 * d * self.n_kv_heads * h + self.n_heads * h * d
         if self.moe is not None:
-            ff = 3 * d * self.moe.d_ff_expert * self.moe.num_experts
+            ff = 3 * d * self.moe.d_ff_expert * self.moe.held[1]
             ff += 3 * d * self.moe.d_ff_shared * self.moe.num_shared_experts
             ff += d * self.moe.num_experts  # router
         elif self.d_ff:
@@ -163,7 +197,8 @@ class ModelConfig:
             ff = 0
         per_layer = attn + ff
         n_l = self.n_layers + self.n_enc_layers
-        return emb + n_l * per_layer
+        dense = self.first_dense_layers * (3 * d * self.first_dense_d_ff - ff)
+        return emb + n_l * per_layer + dense
 
     @property
     def active_param_count(self) -> float:
@@ -171,9 +206,10 @@ class ModelConfig:
         if self.moe is None:
             return self.param_count
         d = self.d_model
-        full_ff = 3 * d * self.moe.d_ff_expert * self.moe.num_experts
+        full_ff = 3 * d * self.moe.d_ff_expert * self.moe.held[1]
         act_ff = 3 * d * self.moe.d_ff_expert * self.moe.top_k
-        return self.param_count - self.n_layers * (full_ff - act_ff)
+        n_moe = self.n_layers - self.first_dense_layers
+        return self.param_count - n_moe * (full_ff - act_ff)
 
 
 # ---------------------------------------------------------------------------

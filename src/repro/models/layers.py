@@ -71,18 +71,48 @@ def apply_norm(p: Params, x: jax.Array, cfg) -> jax.Array:
 # rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies (head_dim/2,); with ``scaling`` (a
+    ``RopeScaling``), YaRN's: the low frequencies divided by its factor,
+    the high ones kept, a linear ramp between the dims whose wavelength
+    fits ``beta_slow`` and ``beta_fast`` turns into the original context."""
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponents)                     # (head_dim/2,)
+    freqs = 1.0 / (theta ** exponents)                    # (head_dim/2,)
+    if scaling is None:
+        return freqs
+
+    def dim_of(rotations):
+        return head_dim * math.log(
+            scaling.original_max_position_embeddings / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return freqs / scaling.factor * (1.0 - keep) + freqs * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 1 at no scaling."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  ``scaling``:
+    YaRN (see :func:`rope_freqs`), whose cos/sin carry the ratio of its
+    two mscales."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                   # (hd/2,)
+    freqs = rope_freqs(head_dim, theta, scaling)          # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]                   # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        ratio = (yarn_mscale(scaling.factor, scaling.mscale)
+                 / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -377,7 +407,8 @@ def _sdpa_deferred(q, k_cache, v_cache, k_new, v_new, *, scale, softcap_val,
 def append_kv(cache_k, cache_v, new_k, new_v, pos):
     """Write every layer's new K/V into the cache at each slot's offset.
 
-    cache_k/v: (L,B,T,nkv,hd); new_k/v: (L,B,S_new,nkv,hd); pos: (B,).
+    cache_k/v: (L,B,T,...) (dense: (L,B,T,nkv,hd); latent: (L,B,T,r));
+    new_k/v: (L,B,S_new,...) alike; pos: (B,).
     One dynamic-update-slice per slot, over the static slot count, in the
     cache's own dimension order: with the cache donated, XLA updates it in
     place.  (A vmap over the slot axis compiles on TPU to a slot-major
@@ -386,7 +417,7 @@ def append_kv(cache_k, cache_v, new_k, new_v, pos):
     new_k = new_k.astype(cache_k.dtype)
     new_v = new_v.astype(cache_v.dtype)
     for b in range(cache_k.shape[1]):
-        at = (0, b, pos[b], 0, 0)
+        at = (0, b, pos[b]) + (0,) * (cache_k.ndim - 3)
         cache_k = jax.lax.dynamic_update_slice(cache_k, new_k[:, b:b + 1], at)
         cache_v = jax.lax.dynamic_update_slice(cache_v, new_v[:, b:b + 1], at)
     return cache_k, cache_v

@@ -1,19 +1,28 @@
-"""Mixture-of-Experts FFN with sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: routing over every expert, computed for the
+experts held here.
 
 Supports the two assigned MoE geometries:
 * Arctic  — 128 routed experts top-2 **plus a parallel dense FFN branch**
   (dense-MoE hybrid: output = dense(x) + moe(x));
-* DeepSeek-V2 — 2 *shared* experts (always on) + 160 routed experts top-6.
+* DeepSeek-V2 — 2 *shared* experts (always on) + 160 routed experts top-6,
+  routed by ``group_limited_greedy`` (see :func:`route`).
 
-Dispatch: tokens are routed to their top-k experts with a fixed per-expert
-capacity C = ceil(N·k/E · capacity_factor).  Token→expert assignment uses the
-standard position-in-expert cumsum; overflowing tokens are dropped (their
-residual path keeps them alive).  Expert compute is a *grouped* matmul with
-the expert dim laid out on the `expert` logical axis — expert-parallel over
-the `model` mesh axis, which makes the all_to_all pattern visible to the
-dry-run.  Note the stream-scheduling connection: the E experts are exactly
-the "parallel branches on different streams" of the paper, realized here as
-one grouped kernel (kernels/stream_pack lowers the same pattern in Pallas).
+Routing scores all ``num_experts``.  Under expert parallelism a chip holds
+``MoEConfig.held`` = (first, count) of them and computes only their part of
+the result (plus the shared experts, which every chip holds); what the
+other experts add is another chip's part.
+
+Two dispatches:
+* **dropless** (:func:`apply_moe` with ``dropless=True``; the serving path
+  of ``decode_step``, both prefill and decode): each held expert runs over
+  every token (capacity = N) and the gates weight its output, so a token's
+  output never depends on its batch neighbours;
+* **capacity** (training ``forward``): tokens go to their top-k experts
+  with a fixed per-expert capacity C = ceil(N·k/E · capacity_factor),
+  overflow dropped (the residual path keeps the token alive); expert
+  compute is a *grouped* matmul with the expert dim on the ``expert``
+  logical axis, expert-parallel over the ``model`` mesh axis.  It needs
+  every expert held.
 
 The router's aux load-balancing loss (Shazeer-style) is returned alongside.
 """
@@ -34,12 +43,13 @@ def init_moe(key, cfg):
     m = cfg.moe
     d = cfg.d_model
     dt = jnp.dtype(cfg.dtype)
+    held = m.held[1]
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, m.num_experts), jnp.float32),
-        "w_gate": dense_init(ks[1], (m.num_experts, d, m.d_ff_expert), dt),
-        "w_up": dense_init(ks[2], (m.num_experts, d, m.d_ff_expert), dt),
-        "w_down": dense_init(ks[3], (m.num_experts, m.d_ff_expert, d), dt, in_axis=1),
+        "w_gate": dense_init(ks[1], (held, d, m.d_ff_expert), dt),
+        "w_up": dense_init(ks[2], (held, d, m.d_ff_expert), dt),
+        "w_down": dense_init(ks[3], (held, m.d_ff_expert, d), dt, in_axis=1),
     }
     a = {
         "router": "fsdp _",
@@ -59,8 +69,39 @@ def init_moe(key, cfg):
     return p, a
 
 
-def apply_moe(p, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) → (out, aux_loss)."""
+def route(probs: jax.Array, m) -> tuple[jax.Array, jax.Array]:
+    """Gates and expert ids (N, top_k) from router probabilities (N, E).
+
+    With ``n_group`` > 1, DeepSeek-V2's ``group_limited_greedy``: a group's
+    score is the highest probability among its E / n_group experts, the
+    ``topk_group`` best groups are kept, and top-k runs over the kept
+    experts only.  Ties go to the lower index (``lax.top_k``).  Gates are
+    renormalised when ``norm_topk_prob``, else scaled by
+    ``routed_scaling_factor``."""
+    N, E = probs.shape
+    scores = probs
+    if m.n_group > 1:
+        group_best = probs.reshape(N, m.n_group, E // m.n_group).max(axis=-1)
+        _, keep = jax.lax.top_k(group_best, m.topk_group)            # (N, topk_group)
+        kept = jnp.zeros((N, m.n_group), bool).at[jnp.arange(N)[:, None], keep].set(True)
+        scores = jnp.where(jnp.repeat(kept, E // m.n_group, axis=1), probs, 0.0)
+    gate_vals, expert_ids = jax.lax.top_k(scores, m.top_k)         # (N, K)
+    if m.norm_topk_prob:
+        gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    else:
+        gate_vals = gate_vals * m.routed_scaling_factor
+    return gate_vals, expert_ids
+
+
+def apply_moe(
+    p, x: jax.Array, cfg, *, dropless: bool = False,
+    live: Optional[jax.Array] = None,
+) -> tuple[jax.Array, jax.Array]:
+    """x: (B, S, D) → (out, aux).
+
+    ``aux`` is the load-balancing loss, or with ``live`` (B,) bool, the
+    routing counts int32[2] over the live rows' tokens: assignments to the
+    experts held here, and held experts that took at least one token."""
     m = cfg.moe
     B, S, D = x.shape
     N = B * S
@@ -70,15 +111,63 @@ def apply_moe(p, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     # ---- router --------------------------------------------------------
     logits = (xf.astype(jnp.float32) @ p["router"])            # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, K)            # (N, K)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, expert_ids = route(probs, m)                    # (N, K)
 
-    # aux load-balance loss (mean prob × token fraction per expert)
-    me = probs.mean(axis=0)
-    ce = jnp.zeros((E,), jnp.float32).at[expert_ids.reshape(-1)].add(1.0) / (N * K)
-    aux = m.router_aux_loss * E * jnp.sum(me * ce)
+    if dropless:
+        out = _dropless(p, xf, gate_vals, expert_ids, cfg)
+    else:
+        out = _capacity(p, xf, gate_vals, expert_ids, cfg)
 
-    # ---- capacity-based dispatch (sort-based) -----------------------------
+    # ---- shared experts (DeepSeek) ---------------------------------------
+    if "shared" in p:
+        sh = p["shared"]
+        sg = gather_fsdp(sh["w_gate"], "fsdp", "mlp", group="moe")
+        su = gather_fsdp(sh["w_up"], "fsdp", "mlp", group="moe")
+        sd = gather_fsdp(sh["w_down"], "mlp", "fsdp", group="moe")
+        hs = _act(xf @ sg, cfg.activation) * (xf @ su)
+        out = out + hs @ sd
+
+    if live is not None:
+        first, count = m.held
+        here = (expert_ids >= first) & (expert_ids < first + count)
+        here &= jnp.repeat(live, S)[:, None]
+        hits = jnp.zeros((E,), jnp.int32).at[expert_ids].add(here.astype(jnp.int32))
+        aux = jnp.stack([here.sum(dtype=jnp.int32),
+                         (hits[first:first + count] > 0).sum(dtype=jnp.int32)])
+    else:
+        # aux load-balance loss (mean prob × token fraction per expert)
+        me = probs.mean(axis=0)
+        ce = jnp.zeros((E,), jnp.float32).at[expert_ids.reshape(-1)].add(1.0) / (N * K)
+        aux = m.router_aux_loss * E * jnp.sum(me * ce)
+    return out.reshape(B, S, D), aux
+
+
+def _dropless(p, xf, gate_vals, expert_ids, cfg) -> jax.Array:
+    """Every held expert over every token, weighted by its gate (0 where
+    the token did not pick it)."""
+    first, count = cfg.moe.held
+    N = xf.shape[0]
+    gates = jnp.zeros((N, cfg.moe.num_experts), jnp.float32)
+    gates = gates.at[jnp.arange(N)[:, None], expert_ids].set(gate_vals)
+    gates = gates[:, first:first + count]                      # (N, held)
+    w_gate = gather_fsdp(p["w_gate"], "expert", "fsdp", "mlp", group="moe")
+    w_up = gather_fsdp(p["w_up"], "expert", "fsdp", "mlp", group="moe")
+    w_down = gather_fsdp(p["w_down"], "expert", "mlp", "fsdp", group="moe")
+    g = _act(jnp.einsum("nd,edf->enf", xf, w_gate), cfg.activation)
+    u = jnp.einsum("nd,edf->enf", xf, w_up)
+    eo = jnp.einsum("enf,efd->end", g * u, w_down)             # (held, N, D)
+    return jnp.einsum("end,ne->nd", eo, gates.astype(xf.dtype))
+
+
+def _capacity(p, xf, gate_vals, expert_ids, cfg) -> jax.Array:
+    """Sort-based capacity dispatch over all (held) experts."""
+    m = cfg.moe
+    N, D = xf.shape
+    E, K = m.num_experts, m.top_k
+    if m.held != (0, E):
+        raise NotImplementedError(
+            "capacity dispatch needs every expert held; a share of the "
+            "experts runs dropless")
     # Small batches (decode steps, smoke tests) run dropless so that
     # step-by-step decode agrees with the full-sequence forward; at scale the
     # paper-standard capacity factor bounds the grouped-matmul shape.
@@ -91,10 +180,8 @@ def apply_moe(p, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     flat_tok = jnp.repeat(jnp.arange(N), K)
 
     # Sort tokens by expert and derive each token's slot from its rank within
-    # the expert's run.  Equivalent ordering to the classic one-hot cumsum
-    # (stable sort preserves token order per expert) at a tiny fraction of
-    # its cost: the (N·K, E) one-hot prefix-sum dominated the whole model's
-    # HLO FLOPs (EXPERIMENTS.md §Perf, deepseek hillclimb).
+    # the expert's run: the classic one-hot cumsum's order (a stable sort keeps
+    # token order per expert) without its (N·K, E) prefix sum.
     order = jnp.argsort(flat_e, stable=True)                   # (N*K,)
     sorted_e = flat_e[order]
     counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
@@ -105,12 +192,9 @@ def apply_moe(p, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     slot = jnp.where(keep, slot, cap)                          # overflow -> pad row
 
     # scatter token features into (E, cap+1, D); row `cap` is the trash slot.
-    # NOTE a 2D (expert x capacity) sharding was tried and refuted: GSPMD
-    # cannot statically plan the data-dependent scatter as an all-to-all and
-    # falls back to replicating the buffers (collective bytes exploded 5x).
-    # The production fix is an explicit shard_map ragged-a2a dispatch;
-    # recorded as future work in EXPERIMENTS.md §Perf (deepseek it5).
-    buf = jnp.zeros((E, cap + 1, D), x.dtype)
+    # (A 2D expert x capacity sharding makes GSPMD replicate the buffers: it
+    # cannot plan the data-dependent scatter as an all-to-all.)
+    buf = jnp.zeros((E, cap + 1, D), xf.dtype)
     buf = buf.at[flat_e, slot].add(jnp.where(keep[:, None], xf[flat_tok], 0))
     buf = constrain(buf, "expert", "_", "_")
     h = buf[:, :cap]                                           # (E, cap, D)
@@ -126,16 +210,5 @@ def apply_moe(p, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
 
     # ---- combine back ----------------------------------------------------
     gathered = eo[flat_e, jnp.minimum(slot, cap - 1)]          # (N*K, D)
-    weight = jnp.where(keep, flat_g, 0.0).astype(x.dtype)
-    out = jnp.zeros((N, D), x.dtype).at[flat_tok].add(gathered * weight[:, None])
-
-    # ---- shared experts (DeepSeek) ---------------------------------------
-    if "shared" in p:
-        sh = p["shared"]
-        sg = gather_fsdp(sh["w_gate"], "fsdp", "mlp", group="moe")
-        su = gather_fsdp(sh["w_up"], "fsdp", "mlp", group="moe")
-        sd = gather_fsdp(sh["w_down"], "mlp", "fsdp", group="moe")
-        hs = _act(xf @ sg, cfg.activation) * (xf @ su)
-        out = out + hs @ sd
-
-    return out.reshape(B, S, D), aux
+    weight = jnp.where(keep, flat_g, 0.0).astype(xf.dtype)
+    return jnp.zeros((N, D), xf.dtype).at[flat_tok].add(gathered * weight[:, None])

@@ -38,7 +38,9 @@ Params = Any
 # per-family layer blocks
 # ---------------------------------------------------------------------------
 
-def _init_attn_block(key, cfg):
+def _init_attn_block(key, cfg, *, dense_ffn: bool = False):
+    """One attention + FFN block; ``dense_ffn``: a leading dense layer of a
+    MoE model (an FFN of width ``first_dense_d_ff`` in the experts' place)."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     if cfg.mla is not None:
         attn_p, attn_a = init_mla(k1, cfg)
@@ -53,7 +55,10 @@ def _init_attn_block(key, cfg):
         n4p, n4a = L.init_norm(cfg)
         p["ln_post_attn"], a["ln_post_attn"] = n3p, n3a
         p["ln_post_ffn"], a["ln_post_ffn"] = n4p, n4a
-    if cfg.moe is not None:
+    if dense_ffn:
+        ffn_p, ffn_a = L.init_ffn(k3, cfg, cfg.first_dense_d_ff)
+        p["ffn"], a["ffn"] = ffn_p, ffn_a
+    elif cfg.moe is not None:
         moe_p, moe_a = init_moe(k2, cfg)
         p["moe"], a["moe"] = moe_p, moe_a
         if cfg.d_ff:  # arctic: parallel dense residual branch
@@ -71,7 +76,9 @@ def _attn_ffn_block(
     """Standard pre-norm transformer block; returns (x, new_cache, aux)."""
     h = L.apply_norm(lp["ln1"], x, cfg)
     if cfg.mla is not None:
-        attn_out, new_cache = mla_attention(lp["attn"], h, cfg, positions=positions, cache=cache)
+        with jax.named_scope("mla"):
+            attn_out, _ = mla_attention(lp["attn"], h, cfg, positions=positions)
+        new_cache = None
     else:
         attn_out, new_cache = L.attention(
             lp["attn"], h, cfg, positions=positions, layer_window=window, cache=cache
@@ -83,8 +90,9 @@ def _attn_ffn_block(
 
     h = L.apply_norm(lp["ln2"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
-        moe_out, aux = apply_moe(lp["moe"], h, cfg)
+    if "moe" in lp:
+        with jax.named_scope("moe"):
+            moe_out, aux = apply_moe(lp["moe"], h, cfg)
         if "ffn" in lp:  # arctic dense residual branch in parallel
             moe_out = moe_out + L.apply_ffn(lp["ffn"], h, cfg)
         ffn_out = moe_out
@@ -111,8 +119,14 @@ def init_model(key, cfg):
 
     fam = cfg.family
     if fam in ("dense", "moe", "vlm"):
-        lp, la = _stacked_layers(keys[1], cfg, cfg.n_layers, _init_attn_block)
+        n_dense = cfg.first_dense_layers
+        lp, la = _stacked_layers(keys[1], cfg, cfg.n_layers - n_dense, _init_attn_block)
         p["layers"], a["layers"] = lp, la
+        if n_dense:
+            dp, da = _stacked_layers(
+                keys[4], cfg, n_dense,
+                functools.partial(_init_attn_block, dense_ffn=True))
+            p["dense_layers"], a["dense_layers"] = dp, da
         if fam == "vlm":
             k1, k2 = jax.random.split(keys[2])
             dt = jnp.dtype(cfg.dtype)
@@ -289,6 +303,8 @@ def forward(p, batch, cfg):
 
         if cfg.remat:
             body = jax.checkpoint(body, prevent_cse=False, policy=_remat_policy(cfg))
+        if "dense_layers" in p:
+            x, _ = jax.lax.scan(body, x, p["dense_layers"])
         xs = p["layers"] if windows is None else (p["layers"], windows)
         x, auxs = jax.lax.scan(body, x, xs, unroll=cfg.layer_unroll)
         aux_total = auxs.sum()
@@ -505,12 +521,18 @@ def cache_axes(cfg, per_slot: bool = True):
     raise ValueError(fam)
 
 
-def decode_step(p, cache, tokens, cfg):
+def decode_step(p, cache, tokens, cfg, *, live=None):
     """One decode step: tokens (B, S_new) → (logits (B,S_new,V), new cache).
 
     ``cache["pos"]`` is per-slot (B,) — every batch slot decodes at its own
     offset (continuous batching; see serving/).  S_new > 1 runs a cached
-    chunked prefill (used by the serving engine's prompt buckets).
+    chunked prefill (used by the serving engine's prompt buckets); with
+    latent attention such a chunk is a prompt from position 0.
+
+    With ``live`` (B,) bool, a MoE model also returns its routing counts
+    over the live slots, summed over its MoE layers: int32[2] of
+    assignments to the experts held here and held experts hit
+    (``moe.apply_moe``); the return is then (logits, cache, counts).
     """
     fam = cfg.family
     pos_raw = jnp.asarray(cache["pos"])
@@ -520,10 +542,12 @@ def decode_step(p, cache, tokens, cfg):
     x = L.embed_tokens(p["embed"], tokens, cfg)
     # per-slot offsets; multi-token chunks get consecutive positions
     positions = pos[:, None] + jnp.arange(S_new, dtype=jnp.int32)[None, :]
+    routing = jnp.zeros((2,), jnp.int32)
 
-    if fam in ("dense", "vlm", "moe"):
+    if fam in ("dense", "vlm", "moe") and cfg.mla is not None:
+        x, new_cache, routing = _decode_mla(p, cache, x, cfg, positions, pos, live)
+    elif fam in ("dense", "vlm", "moe"):
         windows = _window_schedule(cfg)
-        use_mla = cfg.mla is not None
 
         def body(x, inp):
             if windows is None:
@@ -531,58 +555,39 @@ def decode_step(p, cache, tokens, cfg):
                 w = None
             else:
                 lp, (ck, cv), w = inp
-            if use_mla:
-                lcache = {"ckv": ck, "krope": cv, "pos": pos}
-                h = L.apply_norm(lp["ln1"], x, cfg)
-                attn_out, nc = mla_attention(lp["attn"], h, cfg, positions=positions, cache=lcache)
-                x = x + attn_out
-                new_k, new_v = nc["ckv"], nc["krope"]
-            else:
-                lcache = {"k": ck, "v": cv, "pos": pos}
-                h = L.apply_norm(lp["ln1"], x, cfg)
-                # deferred append: read-only cache here; the new K/V of all
-                # layers are written after the scan (see layers.append_kv)
-                attn_out, (new_k, new_v) = L.attention(
-                    lp["attn"], h, cfg, positions=positions, layer_window=w,
-                    cache=lcache, update_cache=False,
-                )
-                if cfg.post_attn_norm:
-                    attn_out = L.apply_norm(lp["ln_post_attn"], attn_out, cfg)
-                x = x + attn_out
-            h = L.apply_norm(lp["ln2"], x, cfg)
-            if cfg.moe is not None:
-                ffn_out, _ = apply_moe(lp["moe"], h, cfg)
-                if "ffn" in lp:
-                    ffn_out = ffn_out + L.apply_ffn(lp["ffn"], h, cfg)
-            else:
-                ffn_out = L.apply_ffn(lp["ffn"], h, cfg)
+            lcache = {"k": ck, "v": cv, "pos": pos}
+            h = L.apply_norm(lp["ln1"], x, cfg)
+            # deferred append: read-only cache here; the new K/V of all
+            # layers are written after the scan (see layers.append_kv)
+            attn_out, (new_k, new_v) = L.attention(
+                lp["attn"], h, cfg, positions=positions, layer_window=w,
+                cache=lcache, update_cache=False,
+            )
             if cfg.post_attn_norm:
-                ffn_out = L.apply_norm(lp["ln_post_ffn"], ffn_out, cfg)
-            return x + ffn_out, (new_k, new_v)
+                attn_out = L.apply_norm(lp["ln_post_attn"], attn_out, cfg)
+            x = x + attn_out
+            ffn_out, counts = _decode_ffn(lp, x, cfg, live)
+            ys = (new_k, new_v) if cfg.moe is None else (new_k, new_v, counts)
+            return x + ffn_out, ys
 
-        if use_mla:
-            kv = (cache["ckv"], cache["krope"])
-        else:
-            kv = (cache["k"], cache["v"])
+        kv = (cache["k"], cache["v"])
         if windows is None:
-            x, new_kv = jax.lax.scan(body, x, (p["layers"], kv), unroll=cfg.layer_unroll)
+            x, ys = jax.lax.scan(body, x, (p["layers"], kv), unroll=cfg.layer_unroll)
         else:
-            x, new_kv = jax.lax.scan(body, x, (p["layers"], kv, windows), unroll=cfg.layer_unroll)
-        if use_mla:
-            new_cache = {"ckv": new_kv[0], "krope": new_kv[1], "pos": cache["pos"] + S_new}
+            x, ys = jax.lax.scan(body, x, (p["layers"], kv, windows), unroll=cfg.layer_unroll)
+        nk, nv = ys[:2]
+        if cfg.moe is not None:
+            routing = ys[2].sum(axis=0)
+        if synced:
+            # one update for all layers and slots, in place when the
+            # cache is donated
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], nk.astype(cache["k"].dtype), (0, 0, pos_raw, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], nv.astype(cache["v"].dtype), (0, 0, pos_raw, 0, 0))
         else:
-            if synced:
-                # one update for all layers and slots, in place when the
-                # cache is donated
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], new_kv[0].astype(cache["k"].dtype),
-                    (0, 0, pos_raw, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], new_kv[1].astype(cache["v"].dtype),
-                    (0, 0, pos_raw, 0, 0))
-            else:
-                ck, cv = L.append_kv(cache["k"], cache["v"], new_kv[0], new_kv[1], pos)
-            new_cache = {"k": ck, "v": cv, "pos": cache["pos"] + S_new}
+            ck, cv = L.append_kv(cache["k"], cache["v"], nk, nv, pos)
+        new_cache = {"k": ck, "v": cv, "pos": cache["pos"] + S_new}
     elif fam == "hybrid":
         x, new_cache = _decode_hybrid(p, cache, x, cfg, positions)
     elif fam == "ssm":
@@ -601,7 +606,65 @@ def decode_step(p, cache, tokens, cfg):
 
     x = L.apply_norm(p["final_norm"], x, cfg)
     logits = L.unembed(p["embed"], x, cfg)
+    if live is not None and cfg.moe is not None:
+        return logits, new_cache, routing
     return logits, new_cache
+
+
+def _decode_ffn(lp, x, cfg, live):
+    """The FFN half of a decode block on the residual ``x``: (its output,
+    routing counts int32[2], zero for a dense FFN).  Experts run dropless."""
+    h = L.apply_norm(lp["ln2"], x, cfg)
+    counts = jnp.zeros((2,), jnp.int32)
+    if "moe" in lp:
+        with jax.named_scope("moe"):
+            ffn_out, aux = apply_moe(lp["moe"], h, cfg, dropless=True, live=live)
+        if live is not None:
+            counts = aux
+        if "ffn" in lp:
+            ffn_out = ffn_out + L.apply_ffn(lp["ffn"], h, cfg)
+    else:
+        ffn_out = L.apply_ffn(lp["ffn"], h, cfg)
+    if cfg.post_attn_norm:
+        ffn_out = L.apply_norm(lp["ln_post_ffn"], ffn_out, cfg)
+    return ffn_out, counts
+
+
+def _decode_mla(p, cache, x, cfg, positions, pos, live):
+    """The layer stack of a latent-attention model over its latent cache:
+    the leading dense layers, then the MoE layers, each group one scan that
+    reads its layers' cache rows (read only); then every layer's new latent
+    rows are written in place, one update per slot (``layers.append_kv``).
+    Returns (x, new cache, routing counts)."""
+    groups = [(p["dense_layers"], 0)] if "dense_layers" in p else []
+    groups.append((p["layers"], cfg.first_dense_layers))
+    ckv_all, krope_all = cache["ckv"], cache["krope"]
+
+    def body(x, inp):
+        lp, li = inp
+        lcache = {"ckv": jax.lax.dynamic_index_in_dim(ckv_all, li, 0, keepdims=False),
+                  "krope": jax.lax.dynamic_index_in_dim(krope_all, li, 0, keepdims=False),
+                  "pos": pos}
+        h = L.apply_norm(lp["ln1"], x, cfg)
+        with jax.named_scope("mla"):
+            attn_out, rows = mla_attention(lp["attn"], h, cfg, positions=positions, cache=lcache)
+        x = x + attn_out
+        ffn_out, counts = _decode_ffn(lp, x, cfg, live)
+        return x + ffn_out, (rows, counts)
+
+    new_ckv, new_krope, routing = [], [], jnp.zeros((2,), jnp.int32)
+    for stack, first in groups:
+        n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        layer_ids = jnp.arange(first, first + n, dtype=jnp.int32)
+        x, ((c_kv, k_rope), counts) = jax.lax.scan(
+            body, x, (stack, layer_ids), unroll=n if cfg.scan_unroll else 1)
+        new_ckv.append(c_kv)
+        new_krope.append(k_rope)
+        routing = routing + counts.sum(axis=0)
+    ckv, krope = L.append_kv(ckv_all, krope_all, jnp.concatenate(new_ckv),
+                             jnp.concatenate(new_krope), pos)
+    S_new = positions.shape[1]
+    return x, {"ckv": ckv, "krope": krope, "pos": cache["pos"] + S_new}, routing
 
 
 def _decode_hybrid(p, cache, x, cfg, positions):

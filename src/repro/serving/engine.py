@@ -111,13 +111,25 @@ def _bind_cfg(body: Callable, cfg) -> Callable:
 
 
 def decode_body(params, cache, tokens, *, cfg):
-    """Sealed decode step: one greedy token for every slot.
+    """Sealed decode step: one greedy token for every slot; a MoE model
+    also returns the step's routing counts over the live slots, int32[2]:
+    assignments to the experts held here and held experts hit, summed over
+    its MoE layers.  A slot the engine freed sits at offset 0
+    (``_finish``), and for a MoE model the step leaves it there, so the
+    live slots are those at a non-zero offset.
 
     Module-level (``cfg`` bound with ``functools.partial``) so the body
     the engine seals can also be lowered from abstract shapes alone."""
-    logits, cache = decode_step(params, cache, tokens, cfg)
+    if cfg.moe is None:
+        logits, cache = decode_step(params, cache, tokens, cfg)
+        routing = ()
+    else:
+        live = cache["pos"] > 0
+        logits, cache, counts = decode_step(params, cache, tokens, cfg, live=live)
+        cache["pos"] = jnp.where(live, cache["pos"], 0)
+        routing = (counts,)
     nxt = jnp.argmax(logits[:, :, : cfg.vocab], axis=-1).astype(jnp.int32)
-    return nxt, cache
+    return (nxt, cache) + routing
 
 
 def prefill_body(params, tokens, cache, slot, true_len, *, cfg):
@@ -550,11 +562,15 @@ class ServingEngine:
                      args={"live": len(live)} if tr.enabled else None):
             with tr.span("engine.h2d", cat="engine"):
                 tokens = jnp.asarray(self._next_tok)
-            nxt, self.kv_cache = self._decode(self.params, self.kv_cache, tokens)
+            nxt, self.kv_cache, *routing = self._decode(self.params, self.kv_cache, tokens)
         self.stats.decode_s += time.perf_counter() - t0
         self.stats.steps += 1
         with tr.span("engine.readback", cat="engine", args=_DECODE_READBACK):
             nxt_np = np.asarray(nxt)
+        if routing and tr.enabled:
+            here, hit = np.asarray(routing[0]).tolist()
+            tr.counter("moe.tokens_here", here, cat="moe")
+            tr.counter("moe.experts_hit", hit, cat="moe")
         for s in live:
             req = self.slots[s]
             req.generated.append(int(nxt_np[s, 0]))

@@ -1,7 +1,7 @@
 """Per-architecture smoke tests (spec deliverable f).
 
 Each assigned architecture instantiates its REDUCED config (≤2 layers,
-d_model ≤ 512, ≤4 experts) and runs one forward + one train step + decode
+d_model ≤ 512, ≤8 experts) and runs one forward + one train step + decode
 steps on CPU, asserting output shapes and the absence of NaNs.  Full configs
 are exercised only via the dry-run (launch/dryrun.py).
 """
@@ -46,7 +46,7 @@ def test_smoke_reduced_config_limits(arch):
     assert cfg.n_layers <= 2
     assert cfg.d_model <= 512
     if cfg.moe is not None:
-        assert cfg.moe.num_experts <= 4
+        assert cfg.moe.num_experts <= 8
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -171,3 +171,50 @@ def test_sealed_prefill_writes_the_cache_in_place(one_chip, served):
     compiled = prefill_program(cfg).lower(
         params, tokens, cache, scalar, scalar).compile()
     _assert_cache_updated_in_place(compiled, cache)
+
+
+# --- the latent cache of the DeepSeek-V2 configuration ----------------------
+
+
+@pytest.fixture(scope="module")
+def latent(one_chip):
+    """``deepseek-v2-ep20``'s params and latent cache, as shapes, at its
+    engine's 64 slots x 4096 positions (9 layers, 8 held experts)."""
+    conf = json.loads((BENCH_CONFIGS / "deepseek-v2-ep20.json").read_text())
+    model = dict(conf["model"])
+    model["mla"] = C.MLAConfig(**model["mla"])
+    model["moe"] = C.MoEConfig(**{**model["moe"],
+                                  "experts_held": tuple(model["moe"]["experts_held"])})
+    model["rope_scaling"] = C.RopeScaling(**model["rope_scaling"])
+    cfg = C.ModelConfig(**model)
+    eng = conf["engine"]
+    params, _ = abstract_model(cfg)
+    cache = jax.eval_shape(
+        lambda: init_cache(cfg, eng["max_slots"], eng["max_len"]))
+    return cfg, eng, _on(params, one_chip), _on(cache, one_chip)
+
+
+def _assert_latent_cache_updated_in_place(compiled, cache):
+    rows = (cache["ckv"], cache["krope"])
+    for leaf in rows:
+        assert leaf.shape[:3] == (9, 64, 4096)
+        assert _copies_of(compiled, math.prod(leaf.shape)) == []
+    nbytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize for leaf in rows)
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert _footprint(compiled) < HBM_BYTES
+
+
+def test_sealed_latent_decode_writes_the_cache_in_place(one_chip, latent):
+    cfg, eng, params, cache = latent
+    tokens = _sds((eng["max_slots"], 1), jnp.int32, one_chip)
+    compiled = decode_program(cfg).lower(params, cache, tokens).compile()
+    _assert_latent_cache_updated_in_place(compiled, cache)
+
+
+def test_sealed_latent_prefill_writes_the_cache_in_place(one_chip, latent):
+    cfg, eng, params, cache = latent
+    scalar = _sds((), jnp.int32, one_chip)
+    tokens = _sds((1, max(eng["buckets"])), jnp.int32, one_chip)
+    compiled = prefill_program(cfg).lower(
+        params, tokens, cache, scalar, scalar).compile()
+    _assert_latent_cache_updated_in_place(compiled, cache)
